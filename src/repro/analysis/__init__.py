@@ -9,9 +9,10 @@ The two paper tools in five lines:
     print(sess.classify(spec).comment)      # Tool 2: utilization -> verdict
 
 Counter acquisition is pluggable: ``Session(provider="kernel")`` reads
-counters back from the interpret-mode instrumented Pallas kernels instead
-of synthesizing the trace, and ``sess.validate(spec)`` compares the two —
-the paper's §5 model-vs-measured validation as one call.
+counters back from the instrumented Pallas kernels (compiled on a TPU,
+interpreted elsewhere) instead of synthesizing the trace, and
+``sess.validate(spec)`` compares the two — the paper's §5
+model-vs-measured validation as one call.
 
 Older entry points (``repro.core.microbench.build_table`` +
 ``repro.core.profiler.profile_scatter_workload``) remain available but are

@@ -59,6 +59,8 @@ class Device:
     cache: CacheModel = CacheModel()
     num_cores: int = 8
     description: str = ""
+    # what ``jax.devices()[0].device_kind`` reports for this chip
+    device_kinds: tuple = ()
 
     # -- table cache ------------------------------------------------------
 
@@ -145,6 +147,7 @@ def register_device(device: Device) -> Device:
 register_device(Device(
     name="v5e",
     description="TPU v5e (default calibration; paper's Titan-V analogue)",
+    device_kinds=("TPU v5 lite", "TPU v5e"),
 ))
 
 # A bandwidth-rich sibling: same scatter-unit calibration scaled to a
@@ -159,6 +162,7 @@ register_device(Device(
                            hbm_bytes=95 * 1024**3),
     scatter=dataclasses.replace(timing.V5E_SCATTER, clock_hz=1.75e9),
     description="TPU v5p (modeled: v5e scatter calibration at v5p clock)",
+    device_kinds=("TPU v5", "TPU v5p"),
 ))
 
 

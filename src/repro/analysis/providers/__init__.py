@@ -13,6 +13,7 @@ from repro.analysis.providers.base import (  # noqa: F401
     PROVIDERS,
     CounterProvider,
     collect_batch_fallback,
+    collection_origin,
     get_provider,
     provider_collect_batch,
     register_provider,

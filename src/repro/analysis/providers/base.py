@@ -13,9 +13,9 @@ constructor accepts:
 
     ``trace``      — synthesize the committed index stream in numpy and
                      derive counters from it (the modeled path; default)
-    ``kernel``     — run the interpret-mode instrumented Pallas kernel
-                     and read ``wave_degrees``/``wave_active`` back (the
-                     measured path)
+    ``kernel``     — run the instrumented Pallas kernel (compiled on a
+                     TPU, interpreted elsewhere) and read its in-kernel
+                     ``wave_degrees`` back (the measured path)
     ``hlo``        — derive bytes/FLOPs/collective traffic from a
                      compiled artifact or HLO text (no scatter counters)
     ``microbench`` — trace counters plus a timing-model wall-time, the
@@ -104,6 +104,19 @@ def provider_collect_batch(
     if batch is None:
         return collect_batch_fallback(provider, specs, device, parallel)
     return batch(specs, device, parallel=parallel)
+
+
+def collection_origin(provider: CounterProvider) -> str:
+    """Where a provider's counters are collected, for cache keys.
+
+    Providers whose counters depend on the machine that ran them (the
+    ``kernel`` provider: platform, device kind, interpreted or compiled)
+    expose it as ``origin()``, so an entry collected on one machine is
+    never served on another.  Everything else is collected alike
+    anywhere: ``""``.
+    """
+    origin = getattr(provider, "origin", None)
+    return origin() if origin is not None else ""
 
 
 PROVIDERS: dict[str, CounterProvider] = {}

@@ -27,6 +27,7 @@ from typing import Optional
 
 import numpy as np
 
+from repro.analysis.providers.base import collection_origin
 from repro.analysis.resilience import TransientProviderError
 from repro.core.counters import CounterSet
 
@@ -86,6 +87,9 @@ class FaultInjectionProvider:
             # always three draws so the schedule is rate-independent
             return (self._rng.random(), self._rng.random(),
                     self._rng.random())
+
+    def origin(self) -> str:
+        return collection_origin(self.inner)
 
     def collect(self, spec, device) -> CounterSet:
         u_fault, u_latency, u_corrupt = self._draw()

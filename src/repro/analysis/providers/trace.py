@@ -5,9 +5,9 @@ counters derived from a wave trace built on the host.  For ``indices``
 and ``trace`` sources that is exactly the old behaviour; for ``kernel``
 sources it *synthesizes the kernel's committed index stream in numpy*
 (``committed_index_stream`` mirrors the in-kernel issue ordering bit for
-bit) instead of launching the interpret-mode kernel — the "modeled"
-column of the paper's §5 model-vs-measured validation, and orders of
-magnitude faster than a Pallas interpret run.
+bit) instead of launching the kernel — the "modeled" column of the
+paper's §5 model-vs-measured validation, and orders of magnitude faster
+than a Pallas interpret run on the CPU.
 """
 
 from __future__ import annotations

@@ -1,9 +1,9 @@
 """Fault-tolerant counter acquisition: retries, timeouts, breakers.
 
 A long-running profiling service cannot assume a provider call returns,
-returns quickly, or returns *sane numbers*: interpret-mode kernel runs
-can hang, a racing cache writer can be killed mid-flight, and an
-instrumented backend can hand back garbage.  This module is the one
+returns quickly, or returns *sane numbers*: kernel runs can hang, a
+racing cache writer can be killed mid-flight, and an instrumented
+backend can hand back garbage.  This module is the one
 place those failure modes are handled, so ``Session`` and the
 ``repro.service`` daemon never see them raw:
 
@@ -473,6 +473,10 @@ class ResilientProvider:
     def _key(prov) -> str:
         return prov.name
 
+    def origin(self) -> str:
+        from repro.analysis.providers.base import collection_origin
+        return collection_origin(self.primary)
+
     def breaker_states(self) -> dict:
         """Per-provider breaker snapshots (the /status payload).
 
@@ -606,7 +610,8 @@ class ResilientProvider:
         if fp is None:
             return None
         try:
-            key = self.stale_cache.key(self.name, fp, device.table_key())
+            key = self.stale_cache.key(self.name, fp, device.table_key(),
+                                       self.origin())
             hit = self.stale_cache.get(key)
         except Exception:
             return None

@@ -33,8 +33,8 @@ from typing import Optional, Sequence, Union
 import numpy as np
 
 from repro.analysis.device import Device, get_device
-from repro.analysis.providers import (CounterProvider, get_provider,
-                                      provider_collect_batch)
+from repro.analysis.providers import (CounterProvider, collection_origin,
+                                      get_provider, provider_collect_batch)
 from repro.analysis.render import rows_to_csv
 from repro.analysis.sweep_cache import SweepCache
 from repro.analysis.workload import WorkloadSpec
@@ -642,9 +642,10 @@ class Session:
         # bulk disk reads for the memo misses
         misses: list[tuple[int, str, Optional[str]]] = []
         if pending and self.sweep_cache is not None:
+            origin = collection_origin(self.provider)
             disk_keys = {
                 i: self.sweep_cache.key(self.provider.name, fp,
-                                        self.device.table_key())
+                                        self.device.table_key(), origin)
                 for i, fp in pending}
             found = self.sweep_cache.get_many(disk_keys.values())
             for i, fp in pending:

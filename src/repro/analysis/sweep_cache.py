@@ -9,10 +9,14 @@ one ``.npz`` per point under ``results/cache/`` (relocate with the
 or via ``SweepCache.clear()``), keyed by
 
     provider name + ``WorkloadSpec.fingerprint()`` + ``Device.table_key()``
-    + a content hash of the counter-producing source files
+    + the provider's collection origin + a content hash of the
+    counter-producing source files
 
 so a different counter source, workload content, launch geometry,
-scatter-unit calibration, or collection *implementation* never collides
+scatter-unit calibration, collecting machine (the kernel provider's
+platform, device kind and interpret mode: counters from an interpreted
+CPU run are never served on a chip), or collection *implementation*
+never collides
 (a PR that changes counter synthesis invalidates old entries by
 construction — stale numbers cannot survive a code change).  Specs whose content cannot be
 hashed (``fingerprint() is None``: opaque ``run`` callables, compiled
@@ -159,9 +163,10 @@ class SweepCache:
         self.root = Path(root) if root is not None else default_cache_root()
 
     def key(self, provider_name: str, fingerprint: str,
-            table_key: str) -> str:
+            table_key: str, origin: str = "") -> str:
+        """Entry key; ``origin`` is ``providers.collection_origin``."""
         payload = (f"v{CACHE_VERSION}|{_collection_code_digest()}|"
-                   f"{provider_name}|{fingerprint}|{table_key}")
+                   f"{provider_name}|{fingerprint}|{table_key}|{origin}")
         return hashlib.sha256(payload.encode()).hexdigest()[:32]
 
     def path(self, key: str) -> Path:

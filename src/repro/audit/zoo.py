@@ -16,7 +16,7 @@ from typing import Optional, Sequence
 from repro.configs import ARCHS, SHAPES, get_config, shape_applicable
 from repro.launch.lowering import (build_lowered, pre_optimization_hlo,
                                    shape_tuned_config)
-from repro.launch.mesh import compat_make_mesh
+from repro.launch.mesh import make_mesh
 
 # step name -> production shape audited for it
 AUDIT_SHAPES = {
@@ -55,7 +55,7 @@ def lower_config_steps(arch: str, *, steps: Optional[Sequence[str]] = None,
     cfg0 = get_config(arch)
     if reduced:
         cfg0 = cfg0.reduced()
-    mesh = compat_make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     out: dict[str, str] = {}
     for step in (steps or AUDIT_SHAPES):
         shape = SHAPES[AUDIT_SHAPES[step]]

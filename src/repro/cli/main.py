@@ -1064,6 +1064,8 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     ap = build_parser()
     args = ap.parse_args(argv)
     _validate_args(ap, args)
+    from repro.kernels import enable_compile_cache  # lazy: jax
+    enable_compile_cache()
     # hlo specs carry no wave trace: route them to the hlo provider unless
     # the user explicitly picked another backend
     if getattr(args, "workload", None) == "hlo" \

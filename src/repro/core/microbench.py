@@ -12,9 +12,10 @@ Here the measurement has two modes:
   on hardware; on a real TPU this mode is replaced by wall-clock timing of
   the same generated access patterns.
 * ``kernel``: additionally *executes* the instrumented Pallas scatter
-  kernel (interpret mode) on synthetic index patterns constructed to have
-  a designed (n, e, c), recovers the counters from instrumentation, checks
-  they match the design (validating the counter path end-to-end), and uses
+  kernel (compiled on a TPU, interpreted elsewhere) on synthetic index
+  patterns constructed to have a designed (n, e, c), recovers the
+  counters from instrumentation, checks they match the design
+  (validating the counter path end-to-end), and uses
   the counted values to index the timing model.  This mirrors the paper's
   point that ``T(n,e,c)`` "does not reveal any hardware implementation
   details" — the table is produced by running code, not by reading specs.
@@ -97,7 +98,7 @@ def _validate_with_kernel(params, num_points: int, seed: int) -> list[dict]:
         idx = make_pattern(n, e, num_bins, seed=int(rng.integers(1 << 31)))
         values = np.ones(idx.shape, np.float32)
         _, counters = scatter_ops.instrumented_scatter_add(
-            idx.reshape(-1), values.reshape(-1), num_bins, wave=idx.shape[1])
+            idx.reshape(-1), values.reshape(-1), num_bins)
         measured_e = counters["O"] / counters["N"]
         out.append({
             "designed": {"n": n, "e": e},

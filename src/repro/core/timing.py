@@ -2,13 +2,12 @@
 
 This module is the *hardware* that ``core.microbench`` measures.  The paper
 (Dong & Pai 2025) measures ``T(n, e, c)`` on a real Titan V / A6000 with a
-wall-clock microbenchmark; this container is CPU-only with TPU as the
-*target*, so wall-clock timing of Pallas ``interpret=True`` runs would
-measure the Python interpreter, not the TPU.  Instead we encode a
-documented, swap-in-replaceable latency model of the v5e vector-unit
-scatter pipeline.  On real hardware, ``microbench.build_table(mode="hw")``
-would time the same kernels and produce a table of identical shape; every
-consumer downstream (qmodel, profiler, roofline) is agnostic to the source.
+wall-clock microbenchmark.  Here the table comes from a documented,
+swap-in-replaceable latency model of the v5e vector-unit scatter
+pipeline instead: the kernels run compiled on a TPU, but no mode times
+them on the chip and fits the table yet (a ``Device`` fitted from such
+timings would produce a table of identical shape).  Every consumer
+downstream (qmodel, profiler, roofline) is agnostic to the source.
 
 The model reproduces the three qualitative behaviours of paper Fig. 1:
 

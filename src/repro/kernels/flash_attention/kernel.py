@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro import kernels
+
 NEG_INF = -2.0e38
 
 
@@ -33,8 +35,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, bq: int, bkv: int,
 
     def body(j, carry):
         m, l, acc = carry
-        k = jax.lax.dynamic_slice_in_dim(k_ref[0], j * bkv, bkv, 0)
-        v = jax.lax.dynamic_slice_in_dim(v_ref[0], j * bkv, bkv, 0)
+        k = k_ref[0, pl.ds(j * bkv, bkv), :]
+        v = v_ref[0, pl.ds(j * bkv, bkv), :]
         s = jax.lax.dot_general(q, k.astype(jnp.float32),
                                 (((1,), (1,)), ((), ())))  # (BQ, BKV)
         if causal:
@@ -60,8 +62,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, bq: int, bkv: int,
 
 def flash_attention_pallas(
     q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
-    causal: bool = True, bq: int = 128, bkv: int = 128,
-    interpret: bool = True) -> jnp.ndarray:
+    causal: bool = True, bq: int = 128,
+    bkv: int = 128) -> jnp.ndarray:
     """q/k/v (H, T, d) -> (H, T, d).  T % bq == 0 and T % bkv == 0."""
     h, t, d = q.shape
     assert t % bq == 0 and t % bkv == 0
@@ -78,5 +80,5 @@ def flash_attention_pallas(
         ],
         out_specs=pl.BlockSpec((1, bq, d), lambda hi, qi: (hi, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((h, t, d), q.dtype),
-        interpret=interpret,
+        interpret=kernels.interpret_mode(),
     )(q, k, v)

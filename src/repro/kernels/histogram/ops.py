@@ -35,34 +35,44 @@ def _pad(img: jnp.ndarray, tile: int) -> tuple[jnp.ndarray, int]:
     return img, pad
 
 
-@functools.partial(jax.jit, static_argnames=(
-    "num_bins", "variant", "tile", "interpret"))
+@functools.partial(jax.jit, static_argnames=("num_bins", "variant", "tile"))
 def histogram(img: jnp.ndarray, *, num_bins: int = 256,
-              variant: str = "hist", tile: int = hk.DEFAULT_TILE,
-              interpret: bool = True) -> jnp.ndarray:
+              variant: str = "hist",
+              tile: int = hk.DEFAULT_TILE) -> jnp.ndarray:
     """(C, num_bins) int32 histogram; `variant` is 'hist' or 'hist2'."""
     reorder = {"hist": False, "hist2": True}[variant]
     padded, pad = _pad(img.astype(jnp.int32), tile)
     out = hk.histogram_pallas(padded, num_bins=num_bins, reorder=reorder,
-                              tile=tile, interpret=interpret)
+                              tile=tile)
     if pad:  # padding pixels are zeros: remove their channel-0-value counts
         out = out.at[:, 0].add(-pad)
     return out
 
 
-@functools.partial(jax.jit, static_argnames=(
-    "num_bins", "variant", "tile", "interpret"))
+@functools.partial(jax.jit, static_argnames=("num_bins", "variant", "tile"))
 def histogram_weighted(img: jnp.ndarray, weights: jnp.ndarray, *,
                        num_bins: int = 256, variant: str = "hist",
-                       tile: int = hk.DEFAULT_TILE,
-                       interpret: bool = True) -> jnp.ndarray:
+                       tile: int = hk.DEFAULT_TILE) -> jnp.ndarray:
     reorder = {"hist": False, "hist2": True}[variant]
     padded, pad = _pad(img.astype(jnp.int32), tile)
     w = jnp.concatenate([weights.astype(jnp.float32),
                          jnp.zeros((pad,), jnp.float32)]) if pad else weights
     return hk.histogram_pallas(padded, num_bins=num_bins, reorder=reorder,
-                               tile=tile, weights=w.astype(jnp.float32),
-                               interpret=interpret)
+                               tile=tile, weights=w.astype(jnp.float32))
+
+
+@functools.partial(jax.jit, static_argnames=("num_bins", "variant", "tile"))
+def _histogram_and_degrees(img: jnp.ndarray, *, num_bins: int, variant: str,
+                           tile: int) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """One jitted instrumented launch: (C, num_bins) counts + wave degrees."""
+    reorder = {"hist": False, "hist2": True}[variant]
+    padded, pad = _pad(img.astype(jnp.int32), tile)
+    hist, degrees = hk.histogram_pallas(
+        padded, num_bins=num_bins, reorder=reorder, tile=tile,
+        instrumented=True)
+    if pad:
+        hist = hist.at[:, 0].add(-pad)
+    return hist, degrees
 
 
 def histogram_instrumented(
@@ -74,7 +84,6 @@ def histogram_instrumented(
     force_fao: bool = False,
     weighted: bool = False,
     num_cores: int = 8,
-    interpret: bool = True,
     waves_per_tile: Optional[int] = None,
     pipeline_depth: int = 2,
 ) -> tuple[jnp.ndarray, counters_mod.WaveTrace]:
@@ -89,14 +98,9 @@ def histogram_instrumented(
     tiling (``tile * channels / LANES``) and, when overridden, also governs
     the round-robin core assignment — it *is* the scheduled tile size.
     """
-    reorder = {"hist": False, "hist2": True}[variant]
-    padded, pad = _pad(img.astype(jnp.int32), tile)
-    hist, degrees = hk.histogram_pallas(
-        padded, num_bins=num_bins, reorder=reorder, tile=tile,
-        instrumented=True, interpret=interpret)
-    if pad:
-        hist = hist.at[:, 0].add(-pad)
-    deg = np.asarray(degrees).reshape(-1)
+    hist, degrees = _histogram_and_degrees(img, num_bins=num_bins,
+                                           variant=variant, tile=tile)
+    deg = np.asarray(degrees)
     num_waves = deg.shape[0]
     if waves_per_tile is None:
         waves_per_tile = default_waves_per_tile(img, tile)
@@ -137,9 +141,10 @@ def committed_index_stream(img, *, num_bins: int = 256,
                            tile: int = hk.DEFAULT_TILE) -> np.ndarray:
     """The flat bin-index stream the kernel commits, synthesized in numpy.
 
-    Mirrors ``kernel._issue_ordered_bins`` (zero-padding to a tile
-    multiple, channel-offset bins, per-lane channel rotation for hist2,
-    step-major ordering within each commit group) without running Pallas —
+    Mirrors ``kernel.commit_layout`` and ``kernel._commit_bins``
+    (zero-padding to a tile multiple, channel-offset bins, per-lane
+    channel rotation for hist2, step-major ordering within each commit
+    group) without running Pallas —
     the modeled counter source the instrumented kernel cross-validates.
     The per-commit-group transform never mixes rows across tiles, so it is
     applied to the whole padded image at once.
@@ -185,7 +190,7 @@ def collect_counters(
 
     The provider hook: ``repro.analysis.providers.InstrumentedKernelProvider``
     calls this so every counter (``O``, ``N``, active lanes) is read back
-    from the interpret-mode Pallas launch, not synthesized.
+    from the Pallas launch, not synthesized.
     """
     img = jnp.asarray(img)
     _, trace = histogram_instrumented(

@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro import kernels
 from repro.kernels import instrumentation as instr
 
 DEFAULT_TILE = 2048
@@ -54,6 +55,7 @@ def _scatter_kernel(ids_ref, val_ref, out_ref, *, seg_block: int):
     out_ref[...] += jax.lax.dot_general(
         onehot.astype(vals.dtype), vals,
         dimension_numbers=(((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
 
 
@@ -69,8 +71,8 @@ def _bincount_kernel(ids_ref, out_ref, *, num_segments: int):
     out_ref[...] += onehot.astype(jnp.int32).sum(axis=0)[None, :]
 
 
-def _scatter_instrumented_kernel(ids_ref, val_ref, out_ref, deg_ref, *,
-                                 seg_block: int):
+def _scatter_instrumented_kernel(ids_ref, rows_ref, val_ref, out_ref,
+                                 deg_ref, *, seg_block: int):
     j = pl.program_id(0)
     i = pl.program_id(1)
 
@@ -87,11 +89,16 @@ def _scatter_instrumented_kernel(ids_ref, val_ref, out_ref, deg_ref, *,
     out_ref[...] += jax.lax.dot_general(
         onehot.astype(vals.dtype), vals,
         dimension_numbers=(((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
-
-    @pl.when(j == 0)  # degree depends only on the id stream; count once
-    def _trace():
-        deg_ref[...] = instr.wave_degrees(ids)[None, :]
+    # the degrees depend only on the id stream, but tile i's block is
+    # written back after every (j, i) step, so every step writes all of it
+    degs = instr.wave_degrees(rows_ref[...])
+    lane = jax.lax.broadcasted_iota(jnp.int32, deg_ref.shape, 2)
+    out = jnp.zeros(deg_ref.shape, jnp.float32)
+    for w, deg in enumerate(degs):
+        out = jnp.where(lane == w, deg, out)
+    deg_ref[...] = out
 
 
 def scatter_add_pallas(
@@ -102,9 +109,12 @@ def scatter_add_pallas(
     tile: int = DEFAULT_TILE,
     seg_block: int = DEFAULT_SEG_BLOCK,
     instrumented: bool = False,
-    interpret: bool = True,
 ):
-    """values (N, D) f32/bf16, ids (N,) int32 in [0, num_segments)."""
+    """values (N, D) f32/bf16, ids (N,) int32 in [0, num_segments).
+
+    With ``instrumented=True`` additionally returns per-wave serialization
+    degrees of the id stream, shape (N/LANES,).
+    """
     n, d = values.shape
     assert n % tile == 0, "pad in ops.py"
     assert num_segments % seg_block == 0 or num_segments < seg_block
@@ -121,20 +131,24 @@ def scatter_add_pallas(
         waves_per_tile = tile // instr.LANES
         kernel = functools.partial(_scatter_instrumented_kernel,
                                    seg_block=seg_block)
+        # the same ids again as lane-dense commit rows for the degrees
+        rows_spec = pl.BlockSpec((tile // instr.ROW, instr.ROW),
+                                 lambda j, i: (i, 0))
         out, deg = pl.pallas_call(
             kernel,
             grid=grid,
-            in_specs=[ids_spec, val_spec],
+            in_specs=[ids_spec, rows_spec, val_spec],
             out_specs=[out_spec,
-                       pl.BlockSpec((1, waves_per_tile), lambda j, i: (i, 0))],
+                       pl.BlockSpec((1, 1, waves_per_tile),
+                                    lambda j, i: (i, 0, 0))],
             out_shape=[
                 jax.ShapeDtypeStruct((num_seg_blocks * seg_block, d),
                                      jnp.float32),
-                jax.ShapeDtypeStruct((n // tile, waves_per_tile),
+                jax.ShapeDtypeStruct((n // tile, 1, waves_per_tile),
                                      jnp.float32)],
-            interpret=interpret,
-        )(ids, values)
-        return out[:num_segments], deg
+            interpret=kernels.interpret_mode(),
+        )(ids, ids.reshape(-1, instr.ROW), values)
+        return out[:num_segments], deg.reshape(-1)
 
     kernel = functools.partial(_scatter_kernel, seg_block=seg_block)
     out = pl.pallas_call(
@@ -144,7 +158,7 @@ def scatter_add_pallas(
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct((num_seg_blocks * seg_block, d),
                                        jnp.float32),
-        interpret=interpret,
+        interpret=kernels.interpret_mode(),
     )(ids, values)
     return out[:num_segments]
 
@@ -154,7 +168,6 @@ def bincount_pallas(
     num_segments: int,
     *,
     tile: int = DEFAULT_TILE,
-    interpret: bool = True,
 ) -> jnp.ndarray:
     """(num_segments,) int32 counts; the MoE dispatch/POPC-class kernel."""
     n = ids.shape[0]
@@ -167,6 +180,6 @@ def bincount_pallas(
         in_specs=[pl.BlockSpec((tile,), lambda i: (i,))],
         out_specs=pl.BlockSpec((1, num_segments), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, num_segments), jnp.int32),
-        interpret=interpret,
+        interpret=kernels.interpret_mode(),
     )(ids)
     return out[0]

@@ -25,31 +25,27 @@ def _pad_n(ids: jnp.ndarray, values: jnp.ndarray, tile: int):
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "num_segments", "tile", "seg_block", "interpret"))
+    "num_segments", "tile", "seg_block"))
 def scatter_add(values: jnp.ndarray, ids: jnp.ndarray, *, num_segments: int,
                 tile: int = sk.DEFAULT_TILE,
-                seg_block: int = sk.DEFAULT_SEG_BLOCK,
-                interpret: bool = True) -> jnp.ndarray:
+                seg_block: int = sk.DEFAULT_SEG_BLOCK) -> jnp.ndarray:
     """Segment-sum: (N, D) values + (N,) ids -> (num_segments, D) f32.
 
     Padding rows carry zero values, so their (id 0) contribution is zero.
     """
     ids, values, _ = _pad_n(ids.astype(jnp.int32), values, tile)
     return sk.scatter_add_pallas(values, ids, num_segments, tile=tile,
-                                 seg_block=seg_block, interpret=interpret)
+                                 seg_block=seg_block)
 
 
-@functools.partial(jax.jit, static_argnames=(
-    "num_segments", "tile", "interpret"))
+@functools.partial(jax.jit, static_argnames=("num_segments", "tile"))
 def bincount(ids: jnp.ndarray, *, num_segments: int,
-             tile: int = sk.DEFAULT_TILE,
-             interpret: bool = True) -> jnp.ndarray:
+             tile: int = sk.DEFAULT_TILE) -> jnp.ndarray:
     """(num_segments,) int32 counts (the MoE dispatch histogram)."""
     n = ids.shape[0]
     ids_p, _, pad = _pad_n(ids.astype(jnp.int32),
                            jnp.zeros((n, 1), jnp.float32), tile)
-    out = sk.bincount_pallas(ids_p, num_segments, tile=tile,
-                             interpret=interpret)
+    out = sk.bincount_pallas(ids_p, num_segments, tile=tile)
     if pad:  # padding ids are 0: remove their counts
         out = out.at[0].add(-pad)
     return out
@@ -98,8 +94,8 @@ def collect_counters(
     """Run the instrumented kernel and return its counters as a CounterSet.
 
     The provider hook: ``repro.analysis.providers.InstrumentedKernelProvider``
-    calls this so every counter is read back from the interpret-mode
-    Pallas launch, not synthesized.
+    calls this so every counter is read back from the Pallas launch, not
+    synthesized.
     """
     _, counters = instrumented_scatter_add(
         ids, values, num_segments, tile=tile, num_cores=num_cores,
@@ -113,16 +109,19 @@ def collect_counters(
         source="kernel", meta={"op": "scatter_add"})
 
 
+_scatter_and_degrees = jax.jit(
+    functools.partial(sk.scatter_add_pallas, instrumented=True),
+    static_argnames=("num_segments", "tile"))
+
+
 def instrumented_scatter_add(
     ids,
     values,
     num_segments: int,
     *,
-    wave: int = instr.LANES,
     tile: int = sk.DEFAULT_TILE,
     num_cores: int = 8,
     job_class: int = timing.FAO,
-    interpret: bool = True,
     waves_per_tile: int | None = None,
     pipeline_depth: int = 2,
 ):
@@ -136,7 +135,6 @@ def instrumented_scatter_add(
     ``pipeline_depth`` set the trace's launch geometry directly — no
     post-construction mutation needed.
     """
-    del wave  # fixed at instr.LANES inside the kernel
     n = np.asarray(ids).reshape(-1).shape[0]
     ids = jnp.asarray(
         committed_id_stream(ids, num_segments, tile=tile))
@@ -147,9 +145,8 @@ def instrumented_scatter_add(
     if pad:
         values = jnp.concatenate(
             [values, jnp.zeros((pad,) + values.shape[1:], values.dtype)])
-    out, deg = sk.scatter_add_pallas(values, ids, num_segments, tile=tile,
-                                     instrumented=True, interpret=interpret)
-    deg = np.asarray(deg).reshape(-1)
+    out, deg = _scatter_and_degrees(values, ids, num_segments, tile=tile)
+    deg = np.asarray(deg)
     num_waves = deg.shape[0]
     if waves_per_tile is None:
         waves_per_tile = tile // instr.LANES

@@ -10,29 +10,21 @@ from __future__ import annotations
 import jax
 
 
-def compat_make_mesh(shape, axes):
-    """``jax.make_mesh`` across jax versions.
-
-    ``axis_types`` (and ``jax.sharding.AxisType``) only exist on newer
-    jax; older versions default every axis to Auto, which is exactly what
-    we request, so omitting the kwarg there is behavior-identical.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto`` (stated, not defaulted)."""
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(shape=(2, 4), axes=("data", "model")):
     """Small mesh over whatever local devices exist (tests)."""
-    return compat_make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def data_axes_of(mesh) -> tuple[str, ...]:

@@ -203,12 +203,15 @@ def target_from_spec(spec) -> LintTarget:
         num_bins = int(p.get("num_bins", 256))
         reorder = p.get("variant", "hist") == "hist2"
         weighted = bool(p.get("weighted", False))
-        operands = [img]
         args = [jnp.asarray(img)]
+        # the kernel reads the image (and weights) in commit layout
+        operands = [np.asarray(hist_kernel.commit_layout(
+            args[0], reorder=reorder))]
         if weighted:
-            w = np.ones((img.shape[0],), np.float32)
-            operands.append(w)
-            args.append(jnp.asarray(w))
+            w = jnp.ones((img.shape[0],), jnp.float32)
+            args.append(w)
+            operands.append(np.asarray(hist_kernel.commit_layout(
+                jnp.broadcast_to(w[:, None], img.shape), reorder=False)))
 
         def fn(im, *w):
             return hist_kernel.histogram_pallas(

@@ -115,15 +115,14 @@ class PallasRecord:
 
     def block_shape(self, ref: int):
         bm = self.block_mappings[ref]
-        return tuple(int(b) for b in bm.block_shape)
+        return tuple(int(b.block_size) for b in bm.block_shape)
 
     def block_for(self, ref: int, operand, step: tuple) -> np.ndarray:
         """Fetch the block an operand ref sees at one grid step."""
-        import jax
+        from jax.extend import core as jcore
 
         bm = self.block_mappings[ref]
-        closed = bm.index_map_jaxpr
-        coords = jax.core.eval_jaxpr(closed.jaxpr, closed.consts, *step)
+        coords = jcore.jaxpr_as_fun(bm.index_map_jaxpr)(*step)
         shape = self.block_shape(ref)
         arr = np.asarray(operand)
         slices = tuple(
@@ -136,11 +135,11 @@ class PallasRecord:
 
 
 def _subjaxprs(value):
-    import jax
+    from jax.extend import core as jcore
 
-    if isinstance(value, jax.core.ClosedJaxpr):
+    if isinstance(value, jcore.ClosedJaxpr):
         yield value.jaxpr
-    elif isinstance(value, jax.core.Jaxpr):
+    elif isinstance(value, jcore.Jaxpr):
         yield value
     elif isinstance(value, (tuple, list)):
         for v in value:
@@ -149,7 +148,7 @@ def _subjaxprs(value):
 
 def _index_map_dep_axes(bm) -> Optional[frozenset]:
     """Grid axes a block index map depends on; None if not a plain map."""
-    import jax
+    from jax.extend import core as jcore
 
     jx = bm.index_map_jaxpr.jaxpr
     if jx.eqns:
@@ -157,7 +156,7 @@ def _index_map_dep_axes(bm) -> Optional[frozenset]:
     pos = {id(v): i for i, v in enumerate(jx.invars)}
     deps = set()
     for ov in jx.outvars:
-        if isinstance(ov, jax.core.Literal):
+        if isinstance(ov, jcore.Literal):
             continue
         i = pos.get(id(ov))
         if i is None:
@@ -169,6 +168,7 @@ def _index_map_dep_axes(bm) -> Optional[frozenset]:
 def find_pallas_calls(fn: Callable, *args, **kwargs) -> list[PallasRecord]:
     """Trace ``fn`` (no kernel execution) and collect pallas_call records."""
     import jax
+    from jax.extend import core as jcore
 
     closed = jax.make_jaxpr(fn)(*args, **kwargs)
     records: list[PallasRecord] = []
@@ -178,7 +178,7 @@ def find_pallas_calls(fn: Callable, *args, **kwargs) -> list[PallasRecord]:
             if eqn.primitive.name == "pallas_call":
                 gm = eqn.params["grid_mapping"]
                 inner = eqn.params["jaxpr"]
-                if isinstance(inner, jax.core.ClosedJaxpr):
+                if isinstance(inner, jcore.ClosedJaxpr):
                     inner_jaxpr, consts = inner.jaxpr, list(inner.consts)
                 else:
                     inner_jaxpr, consts = inner, []
@@ -342,9 +342,9 @@ class _Interpreter:
     # env helpers ------------------------------------------------------
 
     def _read(self, env, var):
-        import jax
+        from jax.extend import core as jcore
 
-        if isinstance(var, jax.core.Literal):
+        if isinstance(var, jcore.Literal):
             val = np.asarray(var.val)
             return SymVal(sym.Const(shape=tuple(val.shape), dtype=val.dtype,
                                     value=val))
@@ -536,8 +536,6 @@ class _Interpreter:
                                         reason="addupdate token"))
 
     def _prim_cond(self, env, eqn):
-        import jax
-
         pred = self._read(env, eqn.invars[0])
         branches = eqn.params["branches"]
         operands = [self._read(env, iv) for iv in eqn.invars[1:]]
@@ -550,8 +548,6 @@ class _Interpreter:
             finally:
                 self.guard_stack.pop()
         for i, ov in enumerate(eqn.outvars):
-            if isinstance(ov, jax.core.DropVar):
-                continue
             shape, dtype = _avals(ov)
             tags = frozenset()
             for outs in outs_per_branch:
@@ -577,13 +573,11 @@ class _Interpreter:
     def _inline_call(self, env, eqn, closed):
         operands = [self._read(env, iv) for iv in eqn.invars]
         outs = self.run(closed.jaxpr, list(closed.consts), operands)
-        import jax
-
         for ov, val in zip(eqn.outvars, outs):
-            if not isinstance(ov, jax.core.DropVar):
-                env[ov] = val
+            env[ov] = val
 
-    def _prim_pjit(self, env, eqn):
+    def _prim_jit(self, env, eqn):
+        # nested jit (jnp's ``%`` and friends trace to one): inline it
         self._inline_call(env, eqn, eqn.params["jaxpr"])
 
     def _prim_closed_call(self, env, eqn):

@@ -35,21 +35,6 @@ import jax.numpy as jnp
 from repro.models import layers
 
 
-def _shard_map(f, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions (experimental on older jax).
-
-    The old experimental version needs ``check_rep=False``: its replication
-    check breaks transposition of collectives that receive a symbolic Zero
-    cotangent (e.g. grads through ``out`` while ``aux`` is unused).
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as sm_old
-    return sm_old(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
-
-
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
     d_model: int
@@ -193,10 +178,7 @@ def _ep_local(p: dict, x_local: jnp.ndarray, cfg: MoEConfig,
     The dispatch bincount is the paper's histogram — returned for the
     instrumented profiler.
     """
-    if hasattr(jax.lax, "axis_size"):
-        d_shards = jax.lax.axis_size(ep_axis)
-    else:  # older jax: axis size via an all-reduce of ones
-        d_shards = jax.lax.psum(1, ep_axis)
+    d_shards = jax.lax.axis_size(ep_axis)
     t, d = x_local.shape
     e_local = cfg.num_experts // d_shards
     gates, ids, aux = route(p, x_local, cfg)            # (T,k)
@@ -281,7 +263,7 @@ def apply_ep(p: dict, x: jnp.ndarray, cfg: MoEConfig, mesh,
         pspec["shared"] = {"w_gate": P(None, tp_axis),
                            "w_up": P(None, tp_axis),
                            "w_down": P(tp_axis, None)}
-    out, aux, disp = _shard_map(
+    out, aux, disp = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(pspec, P(data_axes)),
         out_specs=(P(data_axes), P(), P(data_axes)),
@@ -317,7 +299,7 @@ def apply_sharded(p: dict, x: jnp.ndarray, cfg: MoEConfig, mesh,
         pspec["shared"] = {"w_gate": P(None, tp_axis),
                            "w_up": P(None, tp_axis),
                            "w_down": P(tp_axis, None)}
-    out, aux, disp = _shard_map(
+    out, aux, disp = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(pspec, P(data_axes)),
         out_specs=(P(data_axes), P(), P(data_axes)),

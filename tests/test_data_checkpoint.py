@@ -103,7 +103,7 @@ def test_restore_with_shardings(tmp_path):
     t = _tree()
     store.save(str(tmp_path), 2, t)
     from repro.launch import mesh as mesh_mod
-    mesh = mesh_mod.compat_make_mesh((1,), ("data",))
+    mesh = mesh_mod.make_mesh((1,), ("data",))
     sh = jax.tree.map(lambda _: NamedSharding(mesh, P()), t)
     restored, _ = store.restore(str(tmp_path), t, shardings=sh)
     assert restored["a"].sharding == NamedSharding(mesh, P())

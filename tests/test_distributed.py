@@ -30,7 +30,7 @@ cfg = moe.MoEConfig(d_model=32, d_expert=16, num_experts=8, top_k=2,
 p = moe.init(jax.random.PRNGKey(0), cfg)
 x = jax.random.normal(jax.random.PRNGKey(1), (4, 8, 32), jnp.float32)
 ref, _, _ = moe.apply_local(p, x.reshape(-1, 32), cfg)
-mesh = mesh_mod.compat_make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = mesh_mod.make_mesh((2, 2, 2), ("pod", "data", "model"))
 with mesh:
     out, aux, disp = moe.apply_ep(p, x, cfg, mesh)
 err = np.abs(np.asarray(out).reshape(-1, 32) - np.asarray(ref)).max()
@@ -50,7 +50,7 @@ cfg = moe.MoEConfig(d_model=32, d_expert=16, num_experts=4, top_k=2,
 p = moe.init(jax.random.PRNGKey(0), cfg)
 x = jax.random.normal(jax.random.PRNGKey(1), (4, 8, 32), jnp.float32)
 ref, _, _ = moe.apply_local(p, x.reshape(-1, 32), cfg)
-mesh = mesh_mod.compat_make_mesh((2, 4), ("data", "model"))
+mesh = mesh_mod.make_mesh((2, 4), ("data", "model"))
 with mesh:
     out, _, _ = moe.apply_sharded(p, x, cfg, mesh, data_axes=("data",))
 err = np.abs(np.asarray(out).reshape(-1, 32) - np.asarray(ref)).max()
@@ -74,7 +74,7 @@ params = model.init(jax.random.PRNGKey(0))
 batch = make_batch(cfg, 8, 32)
 loss0, _ = jax.jit(lambda p, b: model.loss(p, b))(params, batch)
 
-mesh = mesh_mod.compat_make_mesh((2, 4), ("data", "model"))
+mesh = mesh_mod.make_mesh((2, 4), ("data", "model"))
 psh = shd.param_shardings(params, cfg, mesh)
 params_s = jax.device_put(params, psh)
 bsh = jax.tree.map(lambda x: NamedSharding(mesh, P(("data",))), batch)
@@ -103,7 +103,7 @@ import dataclasses
 cfg = get_config("granite-moe-1b-a400m").reduced()
 cfg = dataclasses.replace(cfg, dtype="bfloat16")
 model = build_model(cfg)
-mesh = mesh_mod.compat_make_mesh((4, 2), ("data", "model"))
+mesh = mesh_mod.make_mesh((4, 2), ("data", "model"))
 with pctx.use_mesh(mesh, data_axes=("data",), tp_axis="model"):
     tcfg = train_mod.TrainConfig(accum_steps=2)
     step = train_mod.make_train_step(model, tcfg, adamw.AdamWConfig())

@@ -94,7 +94,8 @@ def test_hist_kernel_model_structure():
     models = analyze_callable(target.fn, *target.args, name="hist")
     assert len(models) == 1
     m = models[0]
-    assert m.grid == (PROBE_PIXELS // 2048,)   # one step per 2048-px tile
+    # one step per wave: 1024 commits = 256 px x 4 channels
+    assert m.grid == (PROBE_PIXELS * 4 // 1024,)
     site = m.sites[0]
     assert site.kind == "one_hot_popcount"
     assert site.rmw and site.num_bins == 1024 and site.row_elems == 1

@@ -17,7 +17,7 @@ from repro.audit.zoo import AUDIT_SHAPES, _REDUCED_GEOM
 from repro.configs import ARCHS, SHAPES, get_config, shape_applicable
 from repro.launch.lowering import (build_lowered, pre_optimization_hlo,
                                    shape_tuned_config)
-from repro.launch.mesh import compat_make_mesh
+from repro.launch.mesh import make_mesh
 
 
 def _reduced_shape(step: str):
@@ -41,7 +41,7 @@ def test_reduced_lowering_smoke(arch):
     cfg = get_config(arch).reduced()
     step, shape = _first_applicable(cfg)
     assert step is not None, f"{arch}: no applicable audit step"
-    mesh = compat_make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     cfg_t, loss_chunk, train_kw = shape_tuned_config(cfg, shape, "base")
     lowered = build_lowered(cfg_t, shape, mesh, loss_chunk=loss_chunk,
                             train_kw=train_kw)

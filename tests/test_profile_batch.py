@@ -287,6 +287,35 @@ def test_cache_key_tracks_provider_fingerprint_and_device(tmp_path):
     assert cache.key("trace", "fp1", "v5e-key") == base
 
 
+def test_kernel_cache_entry_from_cpu_is_not_a_chip_hit(tmp_path, sess,
+                                                     monkeypatch):
+    """Kernel-provider keys carry platform, device kind and interpret
+    mode: counters an interpreted CPU run cached are never served as a
+    disk hit to a run on the chip (whose kernels must then launch)."""
+    from repro import kernels
+    spec = WorkloadSpec.from_indices(
+        np.random.default_rng(0).integers(0, 64, 2048), 64, label="ids")
+    cold = Session("v5e", provider="kernel", cache_dir=tmp_path,
+                   persistent_cache=tmp_path / "cache")
+    cset = cold.collect_cached(spec)
+    assert (cset.meta["platform"], cset.meta["interpret"]) == ("cpu", True)
+    warm = Session("v5e", provider="kernel", cache_dir=tmp_path,
+                   persistent_cache=tmp_path / "cache")
+    warm.collect_cached(spec)
+    assert warm.stats["disk_hits"] == 1          # same machine: a hit
+    monkeypatch.setattr(kernels, "backend_info", lambda: {
+        "platform": "tpu", "device_kind": "TPU v5 lite",
+        "interpret": False})
+    chip = Session("v5e", provider="kernel", cache_dir=tmp_path,
+                   persistent_cache=tmp_path / "cache")
+    got = chip.collect_cached(spec)
+    assert chip.stats["disk_hits"] == 0 and chip.stats["collected"] == 1
+    assert got.meta["device_kind"] == "TPU v5 lite"
+    key = SweepCache(tmp_path).key
+    assert key("kernel", "fp1", "v5e-key", "cpu|cpu|interpret=True") \
+        != key("kernel", "fp1", "v5e-key", "tpu|TPU v5 lite|interpret=False")
+
+
 def test_cache_key_tracks_collection_implementation(tmp_path, monkeypatch):
     """Changing the counter-producing code must invalidate old entries:
     the key folds in a digest of the collection source files."""

@@ -125,6 +125,27 @@ def test_kernel_provider_rejects_non_tile_multiple_indices(sess):
         sess.collect(spec, provider="kernel")
 
 
+def test_kernel_provider_records_where_it_ran(sess):
+    spec = WorkloadSpec.from_indices(_uniform_indices(2), 256, label="m")
+    meta = sess.collect(spec, provider="kernel").meta
+    assert meta["platform"] == "cpu" and meta["interpret"] is True
+    assert meta["device_kind"]
+
+
+@pytest.mark.parametrize("kind,match", [
+    ("TPU v5p", "session models 'v5e'"),       # registered, not modeled
+    ("TPU v99", "no registered Device"),       # unknown: no rates assumed
+])
+def test_kernel_provider_checks_the_attached_chip(sess, monkeypatch, kind,
+                                                  match):
+    from repro import kernels
+    monkeypatch.setattr(kernels, "backend_info", lambda: {
+        "platform": "tpu", "device_kind": kind, "interpret": False})
+    spec = WorkloadSpec.from_indices(_uniform_indices(2), 256, label="c")
+    with pytest.raises(RuntimeError, match=match):
+        sess.collect(spec, provider="kernel")
+
+
 def test_kernel_provider_rejects_bare_trace(sess):
     tr = counters.trace_from_indices(_uniform_indices(2), 256)
     spec = WorkloadSpec.from_trace(tr, label="pre-recorded")
