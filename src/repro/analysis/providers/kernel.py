@@ -26,6 +26,7 @@ from repro.analysis.device import DEVICES
 from repro.analysis.providers.base import (collect_batch_fallback,
                                            register_provider)
 from repro.core.counters import CounterFrame, CounterSet
+from repro.obs import telemetry
 
 
 def _backend(device) -> dict:
@@ -80,25 +81,24 @@ class InstrumentedKernelProvider:
             # threading (one definition, shared with resolve_trace); the
             # per-family ops also expose collect_counters() hooks for
             # direct low-level use outside a Session.
-            return CounterSet.from_trace(
-                spec.run_kernel(), label=spec.label,
-                num_cores=spec.num_cores, bytes_read=spec.bytes_read,
-                flops=spec.flops, overhead_cycles=spec.overhead_cycles,
-                source=self.name, meta={"op": spec.kernel.op})
-        if spec.indices is not None:
+            tr, meta = spec.run_kernel(), {"op": spec.kernel.op}
+        elif spec.indices is not None:
             return self._collect_indices(spec)
-        if spec.run is not None:
+        elif spec.run is not None:
             # custom lazy source: by contract it runs an instrumented
             # kernel and returns its trace
-            tr = spec.resolve_trace()
+            tr, meta = spec.resolve_trace(), None
+        else:
+            raise ValueError(
+                f"WorkloadSpec {spec.label!r} has no runnable source — the "
+                f"'kernel' provider needs a kernel | indices | run spec, "
+                f"not a pre-recorded trace or compiled artifact")
+        with telemetry.span("kernel.counters"):
             return CounterSet.from_trace(
                 tr, label=spec.label, num_cores=spec.num_cores,
                 bytes_read=spec.bytes_read, flops=spec.flops,
-                overhead_cycles=spec.overhead_cycles, source=self.name)
-        raise ValueError(
-            f"WorkloadSpec {spec.label!r} has no runnable source — the "
-            f"'kernel' provider needs a kernel | indices | run spec, not "
-            f"a pre-recorded trace or compiled artifact")
+                overhead_cycles=spec.overhead_cycles, source=self.name,
+                meta=meta)
 
     def _collect_indices(self, spec) -> CounterSet:
         """Run a bare index stream through the instrumented scatter-add.

@@ -338,7 +338,7 @@ class Session:
         same columnar batch path sweeps use.
         """
         with _observed("profile", label=spec.label):
-            self._last = self.analyze([spec])
+            self._last = self._analyze([spec])
         return self._last.profiles[0]
 
     def classify(self, spec: WorkloadSpec) -> bottleneck.BottleneckVerdict:
@@ -387,7 +387,7 @@ class Session:
                     f"shard {shard_index}/{shards} owns no points — the "
                     f"grid is smaller than the shard count")
         with _observed("sweep", points=len(specs)):
-            self._last = self.analyze(specs, parallel=parallel)
+            self._last = self._analyze(specs, parallel=parallel)
         return self._last
 
     def analyze(self, specs: Sequence[WorkloadSpec], *,
@@ -407,11 +407,7 @@ class Session:
         if not specs:
             raise ValueError("analyze() needs at least one WorkloadSpec")
         with _observed("analyze", points=len(specs)):
-            _SESSION_POINTS.inc(len(specs))
-            with _telemetry.span("session.collect", points=len(specs)):
-                csets = self.collect_cached_batch(specs, parallel=parallel)
-            with _telemetry.span("session.model", points=len(specs)):
-                return self._as_result(specs, self._profile_batch(csets))
+            return self._analyze(specs, parallel=parallel)
 
     def advise(self, spec: WorkloadSpec, *, catalog=None, depth: int = 2,
                beam_width: int = 8, top_k: int = 5, validate_top: int = 0,
@@ -618,8 +614,9 @@ class Session:
         pending: list[tuple[int, str]] = []   # cache-eligible memo misses
         first_of_fp: dict[str, int] = {}
         duplicates: list[tuple[int, int]] = []
-        for i, spec in enumerate(specs):
-            fp = spec.fingerprint()
+        with _telemetry.span("session.fingerprint", points=len(specs)):
+            fps = [spec.fingerprint() for spec in specs]
+        for i, (spec, fp) in enumerate(zip(specs, fps)):
             if fp is None:
                 out[i] = self.collect(spec)
                 with self._memo_lock:
@@ -665,8 +662,9 @@ class Session:
             by_cores.setdefault(specs[item[0]].num_cores, []).append(item)
         for items in by_cores.values():
             group = [specs[i] for i, _, _ in items]
-            frame = provider_collect_batch(self.provider, group,
-                                           self.device, parallel)
+            with _telemetry.span("session.provider", points=len(group)):
+                frame = provider_collect_batch(self.provider, group,
+                                               self.device, parallel)
             with self._memo_lock:
                 self.stats["collected"] += len(group)
                 self.stats["batch_calls"] += 1
@@ -702,6 +700,16 @@ class Session:
         return self._profile_batch(list(csets))
 
     # -- internals --------------------------------------------------------
+
+    def _analyze(self, specs: list[WorkloadSpec], *,
+                 parallel: Optional[int] = None) -> SweepResult:
+        """``analyze`` unobserved: the entry points that call it (``profile``,
+        ``sweep``) count, time and span themselves."""
+        _SESSION_POINTS.inc(len(specs))
+        with _telemetry.span("session.collect", points=len(specs)):
+            csets = self.collect_cached_batch(specs, parallel=parallel)
+        with _telemetry.span("session.model", points=len(specs)):
+            return self._as_result(specs, self._profile_batch(csets))
 
     def _profile_batch(self, csets: Sequence[CounterSet],
                        ) -> list[profiler.WorkloadProfile]:

@@ -24,6 +24,7 @@ from repro.core import counters as counters_mod
 from repro.core import timing
 from repro.kernels import instrumentation as instr
 from repro.kernels.histogram import kernel as hk
+from repro.obs import telemetry
 
 
 def _pad(img: jnp.ndarray, tile: int) -> tuple[jnp.ndarray, int]:
@@ -98,22 +99,16 @@ def histogram_instrumented(
     tiling (``tile * channels / LANES``) and, when overridden, also governs
     the round-robin core assignment — it *is* the scheduled tile size.
     """
-    hist, degrees = _histogram_and_degrees(img, num_bins=num_bins,
-                                           variant=variant, tile=tile)
-    deg = np.asarray(degrees)
-    num_waves = deg.shape[0]
+    (img,) = instr.to_device(img)
+    hist, deg = instr.launch(_histogram_and_degrees, img, num_bins=num_bins,
+                             variant=variant, tile=tile)
     if waves_per_tile is None:
         waves_per_tile = default_waves_per_tile(img, tile)
-    tiles = np.arange(num_waves) // max(waves_per_tile, 1)
-    job_class = histogram_job_class(force_fao=force_fao, weighted=weighted)
-    trace = counters_mod.WaveTrace(
-        degree=deg,
-        job_class=np.full(num_waves, job_class, np.int32),
-        core=(tiles % num_cores).astype(np.int32),
-        lanes_active=np.full(num_waves, float(instr.LANES)),
-        waves_per_tile=waves_per_tile,
-        pipeline_depth=pipeline_depth,
-    )
+    trace = instr.wave_trace(
+        deg, job_class=histogram_job_class(force_fao=force_fao,
+                                           weighted=weighted),
+        num_cores=num_cores, waves_per_tile=waves_per_tile,
+        pipeline_depth=pipeline_depth)
     return hist, trace
 
 
@@ -192,13 +187,13 @@ def collect_counters(
     calls this so every counter (``O``, ``N``, active lanes) is read back
     from the Pallas launch, not synthesized.
     """
-    img = jnp.asarray(img)
     _, trace = histogram_instrumented(
         img, num_bins=num_bins, variant=variant, tile=tile,
         force_fao=force_fao, weighted=weighted, num_cores=num_cores,
         waves_per_tile=waves_per_tile, pipeline_depth=pipeline_depth)
-    return counters_mod.CounterSet.from_trace(
-        trace, label=label, num_cores=num_cores,
-        bytes_read=image_bytes(img) if bytes_read is None else bytes_read,
-        flops=flops, overhead_cycles=overhead_cycles,
-        source="kernel", meta={"op": "histogram", "variant": variant})
+    with telemetry.span("kernel.counters"):
+        return counters_mod.CounterSet.from_trace(
+            trace, label=label, num_cores=num_cores,
+            bytes_read=image_bytes(img) if bytes_read is None else bytes_read,
+            flops=flops, overhead_cycles=overhead_cycles,
+            source="kernel", meta={"op": "histogram", "variant": variant})

@@ -18,13 +18,22 @@ Layout: the kernels hand the instrumentation their committed stream as
 wave is ``WAVE_ROWS`` consecutive rows.  Every operation is a lane
 rotation, compare, add, max or a reduction of a whole (8, 128)-tiled
 block — nothing changes shape, which is what Mosaic lowers.
+
+Host side, both kernel families launch through the same three steps,
+each a ``repro.obs.telemetry`` span: ``to_device`` (``kernel.h2d``),
+``launch`` (``kernel.launch``, ``kernel.wait``, ``kernel.readback``) and
+``wave_trace`` (``kernel.counters``).
 """
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.core import counters as counters_mod
+from repro.obs import telemetry
 
 LANES = 1024        # one wave = 8 x 128 VPU lane group
 COMMIT_GROUP = 32   # lanes retiring together; conflicts serialize within
@@ -66,3 +75,51 @@ def wave_degrees(rows: jnp.ndarray) -> list:
     # lane sum over LANES is the mean over its groups (exact in float32)
     return [jnp.sum(mult[w * WAVE_ROWS:(w + 1) * WAVE_ROWS]) / LANES
             for w in range(rows.shape[0] // WAVE_ROWS)]
+
+
+def to_device(*arrays) -> tuple:
+    """One ``device_put`` of a launch's host inputs, not waited on.
+
+    The ``kernel.h2d`` span times the call: the host's staging of the
+    copy, which for a large host array is the copy itself.  What is still
+    in flight when it returns lands in ``kernel.wait``, since the device
+    orders the launch after it.  The span's ``bytes`` counts the arrays
+    not already on a device, which are the ones copied.
+    """
+    copied = sum(a.nbytes for a in arrays if not isinstance(a, jax.Array))
+    with telemetry.span("kernel.h2d", bytes=copied):
+        return jax.device_put(arrays)
+
+
+def launch(fn, *args, **static):
+    """Call an instrumented launch ``fn -> (out, degrees)``, wait for its
+    degrees and read them back: ``(out, degrees as numpy)``.
+
+    The degrees' copy to the host is queued with the launch, so that the
+    read-back after the wait finds it under way.
+    """
+    with telemetry.span("kernel.launch"):
+        out, degrees = fn(*args, **static)
+        degrees.copy_to_host_async()
+    with telemetry.span("kernel.wait"):
+        degrees.block_until_ready()
+    with telemetry.span("kernel.readback"):
+        return out, np.asarray(degrees)
+
+
+def wave_trace(degrees: np.ndarray, *, job_class: int, num_cores: int,
+               waves_per_tile: int,
+               pipeline_depth: int) -> counters_mod.WaveTrace:
+    """The ``WaveTrace`` of a launch's read-back wave degrees: every wave
+    of one job class and full lanes, tiles dealt to cores round-robin."""
+    with telemetry.span("kernel.counters"):
+        num_waves = degrees.shape[0]
+        tiles = np.arange(num_waves) // max(waves_per_tile, 1)
+        return counters_mod.WaveTrace(
+            degree=degrees,
+            job_class=np.full(num_waves, job_class, np.int32),
+            core=(tiles % num_cores).astype(np.int32),
+            lanes_active=np.full(num_waves, float(LANES)),
+            waves_per_tile=waves_per_tile,
+            pipeline_depth=pipeline_depth,
+        )

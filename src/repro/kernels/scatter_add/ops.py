@@ -12,6 +12,7 @@ from repro.core import counters as counters_mod
 from repro.core import timing
 from repro.kernels import instrumentation as instr
 from repro.kernels.scatter_add import kernel as sk
+from repro.obs import telemetry
 
 
 def _pad_n(ids: jnp.ndarray, values: jnp.ndarray, tile: int):
@@ -103,10 +104,12 @@ def collect_counters(
         pipeline_depth=pipeline_depth)
     if bytes_read is None:
         bytes_read = float(np.asarray(ids).size * 4)
-    return counters_mod.CounterSet.from_trace(
-        counters["trace"], label=label, num_cores=num_cores,
-        bytes_read=bytes_read, flops=flops, overhead_cycles=overhead_cycles,
-        source="kernel", meta={"op": "scatter_add"})
+    with telemetry.span("kernel.counters"):
+        return counters_mod.CounterSet.from_trace(
+            counters["trace"], label=label, num_cores=num_cores,
+            bytes_read=bytes_read, flops=flops,
+            overhead_cycles=overhead_cycles, source="kernel",
+            meta={"op": "scatter_add"})
 
 
 _scatter_and_degrees = jax.jit(
@@ -135,32 +138,26 @@ def instrumented_scatter_add(
     ``pipeline_depth`` set the trace's launch geometry directly — no
     post-construction mutation needed.
     """
-    n = np.asarray(ids).reshape(-1).shape[0]
-    ids = jnp.asarray(
-        committed_id_stream(ids, num_segments, tile=tile))
-    values = jnp.asarray(values, jnp.float32)
-    if values.ndim == 1:
-        values = values[:, None]
-    pad = ids.shape[0] - n
-    if pad:
-        values = jnp.concatenate(
-            [values, jnp.zeros((pad,) + values.shape[1:], values.dtype)])
-    out, deg = _scatter_and_degrees(values, ids, num_segments, tile=tile)
-    deg = np.asarray(deg)
-    num_waves = deg.shape[0]
+    with telemetry.span("kernel.prepare"):
+        n = np.asarray(ids).reshape(-1).shape[0]
+        ids = committed_id_stream(ids, num_segments, tile=tile)
+        values = np.asarray(values, np.float32)
+        if values.ndim == 1:
+            values = values[:, None]
+        pad = ids.shape[0] - n
+        if pad:
+            values = np.concatenate(
+                [values, np.zeros((pad,) + values.shape[1:], values.dtype)])
+    values, ids = instr.to_device(values, ids)
+    out, deg = instr.launch(_scatter_and_degrees, values, ids, num_segments,
+                            tile=tile)
     if waves_per_tile is None:
-        waves_per_tile = tile // instr.LANES
-    tiles = np.arange(num_waves) // max(waves_per_tile, 1)
-    trace = counters_mod.WaveTrace(
-        degree=deg,
-        job_class=np.full(num_waves, job_class, np.int32),
-        core=(tiles % num_cores).astype(np.int32),
-        lanes_active=np.full(num_waves, float(instr.LANES)),
-        waves_per_tile=waves_per_tile,
-        pipeline_depth=pipeline_depth,
-    )
+        waves_per_tile = default_waves_per_tile(tile)
+    trace = instr.wave_trace(deg, job_class=job_class, num_cores=num_cores,
+                             waves_per_tile=waves_per_tile,
+                             pipeline_depth=pipeline_depth)
     counters = {
-        "N": float(num_waves),
+        "N": float(deg.shape[0]),
         "O": float(deg.sum()),
         "degree": deg,
         "trace": trace,

@@ -13,13 +13,18 @@ resilience -> service pipeline.  Two halves:
 
 * **Spans** — ``trace_scope()`` opens a trace (with a propagated or
   freshly minted trace id) in a ``contextvars`` context, and ``span()``
-  records named, timed sections into it.  The service worker wraps every
+  records named, timed sections into it, each with its ``id`` and the
+  ``parent`` span that encloses it.  The service worker wraps every
   job in a scope so ``/v1/jobs`` responses can carry per-job span
-  summaries and an ``X-Repro-Trace-Id`` header.
+  summaries and an ``X-Repro-Trace-Id`` header.  Once jax is imported,
+  every span is also a ``jax.profiler.TraceAnnotation`` of its name,
+  scope or no scope, so a ``jax.profiler`` trace shows the program's
+  steps on the device trace's clock.
 
 Everything here is stdlib-only and imports nothing from the rest of
 ``repro`` — the analysis and service layers import *us*, never the
-other way around.
+other way around.  Importing it never imports jax: the annotation is
+looked up by the first span that runs after another module imported it.
 
 A global enable switch (``set_enabled``) turns every write into a no-op
 so the ``heatmap_overhead`` benchmark can measure the instrumented
@@ -30,7 +35,9 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
+import itertools
 import re
+import sys
 import threading
 import time
 import uuid
@@ -343,11 +350,32 @@ def reset() -> None:
 # tracing spans
 
 #: spans recorded per trace are capped so a pathological job can't grow
-#: the response body without bound
+#: the response body without bound.  A span is recorded when it closes,
+#: so the spans still open keep a place under the cap: once it is near,
+#: inner spans are dropped and the ones that enclose them are kept.
 MAX_SPANS = 256
 
 _TRACE: contextvars.ContextVar[Optional[dict]] = contextvars.ContextVar(
     "repro_obs_trace", default=None)
+#: (trace record, span id, spans open around it) of the innermost open
+#: span in this context
+_OPEN: contextvars.ContextVar[Optional[tuple]] = contextvars.ContextVar(
+    "repro_obs_open_span", default=None)
+
+#: ``jax.profiler.TraceAnnotation`` once a span has run with jax imported
+_ANNOTATION = None
+_NO_ANNOTATION = contextlib.nullcontext()
+
+
+def _annotation(name: str):
+    """The profiler annotation of a span: a no-op until jax is imported."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        if sys.modules.get("jax") is None:
+            return _NO_ANNOTATION
+        from jax.profiler import TraceAnnotation
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION(name)
 
 
 def new_trace_id() -> str:
@@ -358,11 +386,13 @@ def new_trace_id() -> str:
 def trace_scope(trace_id: Optional[str] = None) -> Iterator[dict]:
     """Open a trace: mint/propagate an id and collect spans inside.
 
-    Nested scopes stack — the inner scope gets its own span list, and
-    the outer one is restored on exit (mirrors ``resilience_scope``).
+    Nested scopes stack — the inner scope gets its own span list and
+    span ids, and the outer one is restored on exit (mirrors
+    ``resilience_scope``).
     """
     rec = {"id": str(trace_id) if trace_id else new_trace_id(),
-           "spans": [], "t0": time.perf_counter()}
+           "spans": [], "t0": time.perf_counter(),
+           "ids": itertools.count(1)}
     token = _TRACE.set(rec)
     try:
         yield rec
@@ -381,29 +411,64 @@ def span_summaries() -> List[dict]:
     return list(rec["spans"]) if rec is not None else []
 
 
-@contextlib.contextmanager
-def span(name: str, **attrs: object) -> Iterator[None]:
+class span:
     """Record a named, timed section into the enclosing trace scope.
 
-    Cheap no-op when telemetry is disabled or no scope is open.
+    Use as ``with span(name, **attrs):``.  The entry (recorded when the
+    section closes) carries ``id``, unique within the trace, and
+    ``parent``: the id of the enclosing span of the same trace, or
+    ``None``.  With jax imported the section is also a
+    ``TraceAnnotation`` named ``name``, whether or not a scope is open.
+    Cheap no-op when telemetry is disabled.  A class rather than a
+    generator: it runs on every kernel launch, and costs about half.
     """
-    rec = _TRACE.get()
-    if not _ENABLED or rec is None:
-        yield
-        return
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        if len(rec["spans"]) < MAX_SPANS:
-            entry = {
-                "name": str(name),
-                "start_ms": round((t0 - rec["t0"]) * 1e3, 3),
-                "dur_ms": round((time.perf_counter() - t0) * 1e3, 3),
-            }
-            if attrs:
-                entry["attrs"] = {k: _jsonable(v) for k, v in attrs.items()}
-            rec["spans"].append(entry)
+
+    __slots__ = ("name", "attrs", "_annotation", "_rec", "_id", "_parent",
+                 "_depth", "_token", "_t0")
+
+    def __init__(self, name: str, **attrs: object) -> None:
+        self.name = name
+        self.attrs = attrs
+        self._annotation = None
+        self._rec = None
+
+    def __enter__(self) -> None:
+        if not _ENABLED:
+            return
+        self._annotation = _annotation(self.name)
+        self._annotation.__enter__()
+        rec = self._rec = _TRACE.get()
+        if rec is None:
+            return
+        self._id = next(rec["ids"])
+        outer = _OPEN.get()
+        if outer is not None and outer[0] is rec:
+            self._parent, self._depth = outer[1], outer[2] + 1
+        else:
+            self._parent, self._depth = None, 0
+        self._token = _OPEN.set((rec, self._id, self._depth))
+        self._t0 = time.perf_counter()
+
+    def __exit__(self, *exc) -> None:
+        rec = self._rec
+        if rec is not None:
+            t1 = time.perf_counter()
+            _OPEN.reset(self._token)
+            # the ``_depth`` spans still open around this one close later
+            if len(rec["spans"]) + self._depth < MAX_SPANS:
+                entry = {
+                    "name": str(self.name),
+                    "id": self._id,
+                    "parent": self._parent,
+                    "start_ms": round((self._t0 - rec["t0"]) * 1e3, 3),
+                    "dur_ms": round((t1 - self._t0) * 1e3, 3),
+                }
+                if self.attrs:
+                    entry["attrs"] = {k: _jsonable(v)
+                                      for k, v in self.attrs.items()}
+                rec["spans"].append(entry)
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
 
 
 def _jsonable(v: object) -> object:

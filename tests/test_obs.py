@@ -16,9 +16,13 @@ propagated trace id plus span summaries.
 import csv
 import io
 import json
+import os
 import re
+import subprocess
+import sys
 import threading
 import urllib.request
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -27,7 +31,8 @@ from repro.analysis import Session, WorkloadSpec
 from repro.analysis.providers.trace import TraceProvider
 from repro.core.counters import COMMIT_GROUP, LANES, bitwise_equal
 from repro.data.images import make_image
-from repro.obs import Heatmap, heatmap_for_spec, heatmap_from_stream
+from repro.obs import (Heatmap, heatmap_for_spec, heatmap_from_stream,
+                       telemetry)
 from repro.obs.telemetry import (OVERFLOW, MetricsRegistry, span,
                                  span_summaries, trace_scope)
 from repro.service import ProfilingService, ServiceConfig
@@ -254,6 +259,171 @@ def test_spans_record_inside_scope_only():
     assert rec["spans"][1]["attrs"] == {"label": "x"}
 
 
+def test_span_ids_record_their_nesting():
+    with trace_scope() as outer:
+        with span("a"):
+            with span("b"):
+                with span("c"):
+                    pass
+            with span("d"):
+                with trace_scope() as inner:
+                    with span("e"):
+                        with span("f"):
+                            pass
+        with span("g"):
+            pass
+    by = {s["name"]: s for s in outer["spans"]}
+    assert set(by) == set("abcdg")
+    assert len({s["id"] for s in outer["spans"]}) == 5
+    assert by["a"]["parent"] is None and by["g"]["parent"] is None
+    assert by["b"]["parent"] == by["d"]["parent"] == by["a"]["id"]
+    assert by["c"]["parent"] == by["b"]["id"]
+    # the inner scope numbers its own spans and roots them at None
+    ib = {s["name"]: s for s in inner["spans"]}
+    assert set(ib) == {"e", "f"}
+    assert ib["e"]["parent"] is None and ib["f"]["parent"] == ib["e"]["id"]
+    assert ib["e"]["id"] == 1
+
+
+def test_a_disabled_span_records_nothing(monkeypatch):
+    opened = []
+    monkeypatch.setattr(telemetry, "_ANNOTATION", opened.append)
+    with telemetry.disabled(), trace_scope() as rec:
+        with span("off"):
+            pass
+    assert rec["spans"] == [] and opened == []
+
+
+def test_a_span_opens_a_trace_annotation_of_its_name(monkeypatch):
+    import contextlib
+
+    opened = []
+
+    @contextlib.contextmanager
+    def annotation(name):
+        opened.append(name)
+        yield
+        opened.append("/" + name)
+    monkeypatch.setattr(telemetry, "_ANNOTATION", annotation)
+    with span("outside.scope"):           # annotated, scope or no scope
+        pass
+    with trace_scope() as rec:
+        with span("a", k=1):
+            with span("b"):
+                pass
+    assert opened == ["outside.scope", "/outside.scope",
+                      "a", "b", "/b", "/a"]
+    assert [s["name"] for s in rec["spans"]] == ["b", "a"]
+
+
+def test_the_copy_is_never_waited_on(monkeypatch):
+    """Traced or not, ``to_device`` runs one path: it stages the copy and
+    returns; the launch's wait absorbs what is still in flight."""
+    import jax
+
+    from repro.kernels import instrumentation as instr
+
+    waited = []
+    wait = jax.block_until_ready
+    monkeypatch.setattr(instr.jax, "block_until_ready",
+                        lambda x: waited.append(1) or wait(x))
+    host = np.arange(8, dtype=np.int32)
+    (dev,) = instr.to_device(host)
+    with trace_scope() as rec:
+        (dev,) = instr.to_device(host)
+        instr.to_device(dev)                # already on the device
+    assert waited == []
+    assert [s["attrs"]["bytes"] for s in rec["spans"]] == [32, 0]
+    np.testing.assert_array_equal(np.asarray(dev), host)
+
+
+def test_the_cap_keeps_the_spans_that_enclose_others():
+    """Spans are recorded as they close, outer ones last: the cap keeps a
+    place for every span still open, and drops inner ones instead."""
+    with trace_scope() as rec:
+        with span("job"):
+            with span("sweep"):
+                for _ in range(2 * telemetry.MAX_SPANS):
+                    with span("step"):
+                        with span("leaf"):
+                            pass
+        with span("after"):                 # the cap is reached
+            pass
+    names = [s["name"] for s in rec["spans"]]
+    assert len(names) == telemetry.MAX_SPANS
+    assert names[-2:] == ["sweep", "job"]
+    by = {s["name"]: s for s in rec["spans"]}
+    assert by["sweep"]["parent"] == by["job"]["id"]
+
+
+def test_telemetry_imports_without_jax():
+    src = str(Path(__file__).resolve().parents[1] / "src")
+    code = ("import sys, repro.obs.telemetry as t\n"
+            "with t.trace_scope() as rec:\n"
+            "    with t.span('x'):\n"
+            "        pass\n"
+            "assert rec['spans'][0]['name'] == 'x'\n"
+            "assert 'jax' not in sys.modules, 'jax imported'\n")
+    proc = subprocess.run([sys.executable, "-c", code],
+                          env={**os.environ, "PYTHONPATH": src},
+                          capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr
+
+
+def test_profile_counts_one_entry_point():
+    calls = telemetry.counter("repro_session_calls_total", "",
+                              ("method",))
+    sess = _session()
+    sess.profile(_hist_spec())          # warm: the table is built once
+    before = {m: calls.value(method=m) for m in ("profile", "analyze")}
+    with trace_scope() as rec:
+        sess.profile(_hist_spec("hist2"))
+    assert calls.value(method="profile") == before["profile"] + 1
+    assert calls.value(method="analyze") == before["analyze"]
+    roots = [s["name"] for s in rec["spans"] if s["parent"] is None]
+    assert roots == ["session.profile"]
+    # the public analyze keeps its own count
+    sess.analyze([_hist_spec()])
+    assert calls.value(method="analyze") == before["analyze"] + 1
+
+
+KERNEL_STEPS = ["session.fingerprint", "kernel.h2d", "kernel.launch",
+                "kernel.wait", "kernel.readback", "kernel.counters"]
+
+
+def test_kernel_profile_spans_on_the_profiler_trace(tmp_path):
+    """The program's spans are profiler annotations: under
+    ``jax.profiler.trace`` a kernel-provider profile shows its steps inside
+    ``session.collect``, in order, on one host thread."""
+    import jax.profiler
+
+    sess = Session("v5e", provider="kernel")
+    img = make_image("solid", 1 << 12)
+    sess.profile(WorkloadSpec.from_histogram(img, label="warm"))
+    with jax.profiler.trace(str(tmp_path)):
+        sess.profile(WorkloadSpec.from_histogram(img + 1, label="traced"))
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    pd = jax.profiler.ProfileData.from_file(str(path))
+    host = next(p for p in pd.planes if p.name == "/host:CPU")
+    lines = [[(e.name, e.start_ns, e.start_ns + e.duration_ns)
+              for e in line.events] for line in host.lines]
+    (events,) = [ev for ev in lines
+                 if any(n == "session.collect" for n, _, _ in ev)]
+    (collect,) = [e for e in events if e[0] == "session.collect"]
+    (profile,) = [e for e in events if e[0] == "session.profile"]
+    (provider,) = [e for e in events if e[0] == "session.provider"]
+    assert profile[1] <= collect[1] and collect[2] <= profile[2]
+    assert collect[1] <= provider[1] and provider[2] <= collect[2]
+    inside = sorted((s, n) for n, s, e in events
+                    if n in KERNEL_STEPS and collect[1] <= s
+                    and e <= collect[2])
+    order = [n for _, n in inside]
+    assert [n for i, n in enumerate(order) if n not in order[:i]] == \
+        KERNEL_STEPS
+    assert all(provider[1] <= s for s, n in inside
+               if n.startswith("kernel."))
+
+
 # -- service surface ----------------------------------------------------------
 
 
@@ -284,6 +454,22 @@ def test_service_heatmap_kind_and_trace_ids(service):
         {"kind": "heatmap",
          "workload": {"workload": "indices", "size": [1024, 2048]}})
     assert status == 400
+
+
+def test_a_large_sweep_job_keeps_its_outer_spans(service):
+    """A sweep of more points than the span cap still returns the spans
+    that attribute the job: dispatch, analyze, collect and model."""
+    status, body = service.handle(
+        {"kind": "sweep",
+         "workload": {"workload": "indices", "dist": "solid",
+                      "size": [1024 * k for k in range(1, 16)],
+                      "waves_per_tile": list(range(1, 21))}})
+    assert status == 200, body
+    assert len(body["result"]["points"]) == 300
+    names = {s["name"] for s in body["spans"]}
+    assert {"service.dispatch", "session.analyze", "session.collect",
+            "session.fingerprint", "session.model"} <= names
+    assert len(body["spans"]) <= telemetry.MAX_SPANS
 
 
 def test_service_status_includes_cache_stats(service):
