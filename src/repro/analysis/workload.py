@@ -24,12 +24,66 @@ from __future__ import annotations
 import dataclasses
 import hashlib
 import itertools
+import os
+from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Optional
 
 import numpy as np
 
 from repro.core import counters as counters_mod
 from repro.core import timing
+from repro.obs import telemetry as _telemetry
+
+#: arrays of at least this many bytes are hashed as a sha256 tree: fixed
+#: chunks hashed on a thread pool (hashlib releases the interpreter lock
+#: while it hashes), then the chunk digests in order.  Smaller arrays are
+#: hashed flat, the same byte stream as ``arr.tobytes()``.
+_TREE_MIN_BYTES = 8 << 20
+_TREE_CHUNK_BYTES = 4 << 20
+_TREE_TAG = b"sha256-tree/4MiB"
+_TREE_MAX_WORKERS = 8
+
+_FINGERPRINT_BYTES = _telemetry.counter(
+    "repro_fingerprint_bytes_total",
+    "array bytes hashed by WorkloadSpec.fingerprint, by path "
+    "(flat: one sha256 over the buffer; chunked: the sha256 tree)",
+    ("path",))
+
+
+def _usable_cores() -> int:
+    try:
+        return len(os.sched_getaffinity(0))
+    except AttributeError:      # no affinity call off Linux
+        return os.cpu_count() or 1
+
+
+def _chunk_digest(chunk: np.ndarray) -> bytes:
+    return hashlib.sha256(chunk).digest()
+
+
+def _hash_array(h, arr: np.ndarray) -> None:
+    """Feed a C-contiguous array's bytes to ``h`` in place, never copied.
+
+    The digest depends on the bytes alone, never on the number of
+    workers, so keys stay valid across processes and machines.
+    """
+    data = np.frombuffer(arr, np.uint8)
+    if data.nbytes < _TREE_MIN_BYTES:
+        _FINGERPRINT_BYTES.inc(data.nbytes, path="flat")
+        h.update(data)
+        return
+    _FINGERPRINT_BYTES.inc(data.nbytes, path="chunked")
+    chunks = [data[i:i + _TREE_CHUNK_BYTES]
+              for i in range(0, data.nbytes, _TREE_CHUNK_BYTES)]
+    workers = min(_usable_cores(), _TREE_MAX_WORKERS, len(chunks))
+    if workers > 1:
+        with ThreadPoolExecutor(workers) as pool:
+            digests = list(pool.map(_chunk_digest, chunks))
+    else:
+        digests = [_chunk_digest(c) for c in chunks]
+    h.update(_TREE_TAG)
+    h.update(str(len(chunks)).encode())
+    h.update(b"".join(digests))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -134,6 +188,8 @@ class WorkloadSpec:
         point but does not change the measurement (the cache relabels).
         Opaque sources (``run`` callables, ``compiled`` artifacts) are not
         hashable by content: returns ``None``, meaning "never memoize".
+        Arrays are hashed in place; one of 8 MiB or more is hashed as a
+        sha256 tree of 4 MiB chunks in parallel (``_hash_array``).
         """
         if self.run is not None or self.compiled is not None:
             return None
@@ -145,7 +201,7 @@ class WorkloadSpec:
                     arr = np.ascontiguousarray(part)
                     h.update(str(arr.dtype).encode())
                     h.update(str(arr.shape).encode())
-                    h.update(arr.tobytes())
+                    _hash_array(h, arr)
                 else:
                     h.update(repr(part).encode())
                 h.update(b"|")

@@ -1,6 +1,7 @@
 """The repro.analysis session API: Device registry, WorkloadSpec, Session."""
 
 import dataclasses
+import hashlib
 import json
 
 import numpy as np
@@ -13,6 +14,7 @@ from repro.analysis import (
     get_device,
 )
 from repro.analysis import device as device_mod
+from repro.analysis import workload as workload_mod
 from repro.core import counters
 from repro.core.profiler import CacheModel
 
@@ -309,6 +311,153 @@ def test_spec_fingerprint_content_keyed():
     assert a.fingerprint() != c.fingerprint()      # geometry matters
     assert a.fingerprint() != d.fingerprint()      # content matters
     assert WorkloadSpec(label="r", run=lambda: None).fingerprint() is None
+
+
+# -- fingerprint: arrays hashed in place, large ones as a sha256 tree ---------
+
+MiB = 1 << 20
+CHUNK = workload_mod._TREE_CHUNK_BYTES
+
+
+def _pin_histogram(img):
+    return WorkloadSpec.from_histogram(img, label="pin", variant="hist2",
+                                       bytes_read=256.0)
+
+
+def _pin_img():
+    return (np.arange(64 * 4, dtype=np.int32).reshape(64, 4) * 7) % 256
+
+
+def _tobytes_fingerprint(spec):
+    """The formula before arrays were hashed in place, for ``indices``
+    specs: every array copied out with ``tobytes``."""
+    h = hashlib.sha256()
+    for part in ("indices", spec.indices, spec.num_bins, spec.job_class,
+                 spec.waves_per_tile, spec.pipeline_depth, spec.num_cores,
+                 spec.num_devices, spec.bytes_read, spec.flops,
+                 spec.overhead_cycles):
+        if isinstance(part, np.ndarray):
+            arr = np.ascontiguousarray(part)
+            h.update(str(arr.dtype).encode())
+            h.update(str(arr.shape).encode())
+            h.update(arr.tobytes())
+        else:
+            h.update(repr(part).encode())
+        h.update(b"|")
+    return h.hexdigest()
+
+
+def _big(nbytes=3 * CHUNK):
+    return np.arange(nbytes, dtype=np.uint32).astype(np.uint8)
+
+
+@pytest.mark.parametrize("spec, digest", [
+    (WorkloadSpec(label="pin", indices=np.arange(4096, dtype=np.int32) % 256),
+     "36195f094902ca773548603ddbee2b151227de1b18c5f408abd835b6e55063d2"),
+    (_pin_histogram(_pin_img()),
+     "9e520fb0988d43511158fbceabe2c622d8f186260cb36cb56c340bae95f3d8d1"),
+    (_pin_histogram(np.asfortranarray(_pin_img())),
+     "9e520fb0988d43511158fbceabe2c622d8f186260cb36cb56c340bae95f3d8d1"),
+], ids=["indices", "histogram", "histogram-fortran"])
+def test_fingerprint_small_array_digest_pinned(spec, digest):
+    assert spec.fingerprint() == digest
+
+
+@pytest.mark.parametrize("arr", [
+    np.arange(4096, dtype=np.int64),
+    np.arange(12, dtype=np.float32).reshape(3, 4),
+    np.asarray(7, np.int16),
+    np.zeros(0, np.int8),
+    np.arange(24, dtype=np.int32).reshape(2, 3, 4)[:, ::2, 1:],
+    np.ones(MiB - 1, np.int64),        # 8 bytes under the tree's threshold
+], ids=["int64", "2d", "0d", "empty", "strided", "under-8MiB"])
+def test_fingerprint_flat_path_matches_tobytes_formula(arr):
+    spec = WorkloadSpec(label="x", indices=arr)
+    assert spec.fingerprint() == _tobytes_fingerprint(spec)
+
+
+@pytest.mark.parametrize("workers", [1, 4])
+def test_fingerprint_tree_independent_of_workers(monkeypatch, workers):
+    pools = []
+
+    class Pool(workload_mod.ThreadPoolExecutor):
+        def __init__(self, max_workers):
+            pools.append(max_workers)
+            super().__init__(max_workers)
+
+    arr = _big(3 * CHUNK + 1000)
+    spec = WorkloadSpec(label="x", indices=arr)
+    monkeypatch.setattr(workload_mod, "ThreadPoolExecutor", Pool)
+    monkeypatch.setattr(workload_mod, "_usable_cores", lambda: workers)
+    first, second = spec.fingerprint(), spec.fingerprint()
+    assert pools == ([] if workers == 1 else [workers, workers])
+    # the tree, worked out here: tag, chunk count, chunk digests in order
+    h = hashlib.sha256()
+    for part in ("indices", arr):
+        if isinstance(part, np.ndarray):
+            h.update(b"uint8" + str(arr.shape).encode())
+            h.update(b"sha256-tree/4MiB" + b"4")
+            h.update(b"".join(hashlib.sha256(arr[i:i + CHUNK]).digest()
+                              for i in range(0, arr.nbytes, CHUNK)))
+        else:
+            h.update(repr(part).encode())
+        h.update(b"|")
+    for part in (spec.num_bins, spec.job_class, spec.waves_per_tile,
+                 spec.pipeline_depth, spec.num_cores, spec.num_devices,
+                 spec.bytes_read, spec.flops, spec.overhead_cycles):
+        h.update(repr(part).encode() + b"|")
+    assert first == second == h.hexdigest()
+    assert first != _tobytes_fingerprint(spec)
+
+
+@pytest.mark.parametrize("nbytes, at", [
+    (3 * CHUNK, 0),                      # first chunk
+    (3 * CHUNK, CHUNK + CHUNK // 2),     # a middle chunk
+    (3 * CHUNK, 3 * CHUNK - 1),          # the last chunk
+    (3 * CHUNK + 1000, 3 * CHUNK + 999),  # a short final chunk
+], ids=["first", "middle", "last", "short-final"])
+def test_fingerprint_tree_sees_every_byte(nbytes, at):
+    arr = _big(nbytes)
+    before = WorkloadSpec(label="x", indices=arr).fingerprint()
+    arr[at] ^= 1
+    assert WorkloadSpec(label="x", indices=arr).fingerprint() != before
+
+
+@pytest.mark.parametrize("nbytes", [4096, 3 * CHUNK], ids=["flat", "tree"])
+@pytest.mark.parametrize("other", [
+    lambda a: a.view(np.int32),
+    lambda a: a.reshape(2, -1),
+], ids=["dtype", "shape"])
+def test_fingerprint_frames_dtype_and_shape(nbytes, other):
+    arr = _big(nbytes)
+    fp = WorkloadSpec(label="x", indices=arr).fingerprint()
+    assert WorkloadSpec(label="x", indices=other(arr)).fingerprint() != fp
+
+
+@pytest.mark.parametrize("rows", [64, 3 * CHUNK // 16], ids=["flat", "tree"])
+@pytest.mark.parametrize("layout", [
+    np.asfortranarray,
+    lambda a: np.concatenate([a, a], axis=1)[:, ::2],
+], ids=["fortran", "strided"])
+def test_fingerprint_layout_hashes_as_c_copy(rows, layout):
+    img = _big(rows * 4 * 4).view(np.int32).reshape(rows, 4)
+    view = layout(img)
+    assert not view.flags.c_contiguous
+    assert (_pin_histogram(view).fingerprint()
+            == _pin_histogram(np.ascontiguousarray(view)).fingerprint())
+
+
+def test_fingerprint_counts_bytes_by_path():
+    hashed = workload_mod._telemetry.counter(
+        "repro_fingerprint_bytes_total", "", ("path",))
+    small, big = np.zeros(1000, np.int32), _big(3 * CHUNK)
+    before = {p: hashed.value(path=p) for p in ("flat", "chunked")}
+    WorkloadSpec(label="s", indices=small).fingerprint()
+    assert hashed.value(path="flat") == before["flat"] + small.nbytes
+    assert hashed.value(path="chunked") == before["chunked"]
+    WorkloadSpec(label="b", indices=big).fingerprint()
+    assert hashed.value(path="flat") == before["flat"] + small.nbytes
+    assert hashed.value(path="chunked") == before["chunked"] + big.nbytes
 
 
 def test_sweep_parallel_matches_serial(sess):
