@@ -25,6 +25,7 @@ import dataclasses
 import hashlib
 import itertools
 import os
+import sys
 from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Optional
 
@@ -48,6 +49,12 @@ _FINGERPRINT_BYTES = _telemetry.counter(
     "array bytes hashed by WorkloadSpec.fingerprint, by path "
     "(flat: one sha256 over the buffer; chunked: the sha256 tree)",
     ("path",))
+
+
+_ROUTED_TOKENS = _telemetry.counter(
+    "repro_moe_routed_tokens_total",
+    "tokens routed on the device by the kernel provider's moe_router "
+    "launches, by layer", ("layer",))
 
 
 def _usable_cores() -> int:
@@ -84,6 +91,24 @@ def _hash_array(h, arr: np.ndarray) -> None:
     h.update(_TREE_TAG)
     h.update(str(len(chunks)).encode())
     h.update(b"".join(digests))
+
+
+def _device_arrays(values) -> list:
+    """The device-resident (``jax.Array``) members of ``values``."""
+    jax = sys.modules.get("jax")    # no jax imported: no device arrays
+    if jax is None:
+        return []
+    return [v for v in values if isinstance(v, jax.Array)]
+
+
+def _device_digests(arrays: list) -> list:
+    """Digests of device-resident arrays, computed on the device; only
+    the digests come back (``repro.kernels.digest``)."""
+    from repro.kernels import digest  # lazy: jax
+
+    with _telemetry.span("kernel.digest", arrays=len(arrays),
+                         bytes=sum(a.nbytes for a in arrays)):
+        return digest.digests(arrays)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -188,16 +213,32 @@ class WorkloadSpec:
         point but does not change the measurement (the cache relabels).
         Opaque sources (``run`` callables, ``compiled`` artifacts) are not
         hashable by content: returns ``None``, meaning "never memoize".
-        Arrays are hashed in place; one of 8 MiB or more is hashed as a
-        sha256 tree of 4 MiB chunks in parallel (``_hash_array``).
+        Host arrays are hashed in place; one of 8 MiB or more is hashed as
+        a sha256 tree of 4 MiB chunks in parallel (``_hash_array``).
+        Device-resident kernel parameters (``jax.Array``) are digested on
+        the device and never copied to the host (``kernel.digest``): the
+        fingerprint then holds their 128-bit device digests
+        (``repro.kernels.digest``, not cryptographic), with dtype and
+        shape, under the sha256 of the rest.
         """
         if self.run is not None or self.compiled is not None:
             return None
         h = hashlib.sha256()
+        on_device: dict = {}
+        if self.kernel is not None:
+            arrays = _device_arrays(self.kernel.params.values())
+            if arrays:
+                on_device = {id(a): d for a, d in
+                             zip(arrays, _device_digests(arrays))}
 
         def put(*parts) -> None:
             for part in parts:
-                if isinstance(part, np.ndarray):
+                if id(part) in on_device:
+                    h.update(b"device-digest")
+                    h.update(str(part.dtype).encode())
+                    h.update(str(part.shape).encode())
+                    h.update(on_device[id(part)])
+                elif isinstance(part, np.ndarray):
                     arr = np.ascontiguousarray(part)
                     h.update(str(arr.dtype).encode())
                     h.update(str(arr.shape).encode())
@@ -216,7 +257,8 @@ class WorkloadSpec:
             put("kernel", self.kernel.op)
             for k in sorted(self.kernel.params):
                 v = self.kernel.params[k]
-                v = np.asarray(v) if hasattr(v, "shape") else v
+                if id(v) not in on_device and hasattr(v, "shape"):
+                    v = np.asarray(v)
                 put(k, v)
         elif self.hlo_text is not None:
             put("hlo", self.hlo_text)
@@ -278,7 +320,32 @@ class WorkloadSpec:
                 waves_per_tile=self.waves_per_tile,
                 pipeline_depth=self.pipeline_depth or 2)
             return c["trace"]
+        if self.kernel.op == "moe_router":
+            return self._run_router()
         raise ValueError(f"unknown kernel op {self.kernel.op!r}")
+
+    def _run_router(self) -> counters_mod.WaveTrace:
+        """Route the batch on the device and count its expert loads there:
+        one program, the ids never leave the device."""
+        import jax  # lazy: jax
+
+        from repro.kernels.scatter_add import ops as scat_ops
+        from repro.models import moe
+
+        p = self.kernel.params
+        cfg = p["cfg"]
+        with _telemetry.span("moe.route", layer=p["layer"]):
+            program = scat_ops.count_program(moe.expert_stream(cfg),
+                                             cfg.num_experts)
+            router = {"w": p["router_w"], "bias": p["router_bias"]}
+            router = {k: v if isinstance(v, jax.Array) else jax.device_put(v)
+                      for k, v in router.items()}
+        _, c = scat_ops.instrumented_count(
+            program, p["hidden"], router, num_cores=self.num_cores,
+            job_class=p["job_class"], waves_per_tile=self.waves_per_tile,
+            pipeline_depth=self.pipeline_depth or 2)
+        _ROUTED_TOKENS.inc(p["hidden"].shape[0], layer=str(p["layer"]))
+        return c["trace"]
 
     # -- constructors -----------------------------------------------------
 
@@ -328,6 +395,34 @@ class WorkloadSpec:
                        "ids": ids, "values": values,
                        "num_segments": num_segments,
                        "job_class": job_class}),
+                   **spec_kw)
+
+    @classmethod
+    def from_moe_router(cls, layer_params: dict, hidden, cfg, *, label: str,
+                        layer: object = 0, job_class: int = timing.FAO,
+                        **kw) -> "WorkloadSpec":
+        """One MoE layer's expert-load count, routed on the device.
+
+        ``layer_params`` holds the layer's ``"router"`` (``w`` and, for
+        sigmoid scoring, ``bias``; the experts are not read), ``hidden``
+        the (T, d_model) batch of hidden states the router sees, and
+        ``cfg`` the layer's ``repro.models.moe.MoEConfig``.  The kernel
+        provider routes the batch and counts the token-major id stream
+        (T x top_k ids, unit values, ``num_experts`` segments) in one
+        device program.  Keep ``hidden`` and the router on the device
+        (``jax.Array``): they are then fingerprinted there and never
+        copied.  ``layer`` labels the layer in spans and counters.
+        ``bytes_read`` defaults to the count's id reads, 4 bytes an id.
+        """
+        router = layer_params["router"]
+        spec_kw = dict(kw)
+        spec_kw.setdefault("bytes_read",
+                           float(hidden.shape[0] * cfg.top_k * 4))
+        return cls(label=label,
+                   kernel=KernelSource(op="moe_router", params={
+                       "hidden": hidden, "router_w": router["w"],
+                       "router_bias": router.get("bias"), "cfg": cfg,
+                       "layer": layer, "job_class": job_class}),
                    **spec_kw)
 
     @classmethod

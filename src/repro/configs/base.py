@@ -40,6 +40,10 @@ class ModelConfig:
     num_shared_experts: int = 0
     moe_capacity_factor: float = 1.25
     moe_bf16_combine: bool = False
+    moe_scoring: str = "softmax"    # softmax | sigmoid (DeepSeek-V3 noaux_tc)
+    moe_n_group: int = 1
+    moe_topk_group: int = 1
+    moe_routed_scaling_factor: float = 1.0
     # SSM / RWKV / hybrid
     ssm_state: int = 0
     ssm_head_dim: int = 64
@@ -159,6 +163,8 @@ class ModelConfig:
             top_k=min(2, self.top_k),
             d_expert=64 if self.d_expert else 0,
             num_shared_experts=min(1, self.num_shared_experts),
+            moe_n_group=min(4, self.moe_n_group),
+            moe_topk_group=min(2, self.moe_topk_group),
             ssm_state=min(16, self.ssm_state),
             ssm_head_dim=16 if self.ssm_state else 64,
             attn_every=min(2, self.attn_every),

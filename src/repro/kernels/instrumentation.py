@@ -108,11 +108,12 @@ def launch(fn, *args, **static):
 
 
 def wave_trace(degrees: np.ndarray, *, job_class: int, num_cores: int,
-               waves_per_tile: int,
-               pipeline_depth: int) -> counters_mod.WaveTrace:
+               waves_per_tile: int, pipeline_depth: int,
+               **span_attrs) -> counters_mod.WaveTrace:
     """The ``WaveTrace`` of a launch's read-back wave degrees: every wave
-    of one job class and full lanes, tiles dealt to cores round-robin."""
-    with telemetry.span("kernel.counters"):
+    of one job class and full lanes, tiles dealt to cores round-robin.
+    ``span_attrs`` go on the ``kernel.counters`` span."""
+    with telemetry.span("kernel.counters", **span_attrs):
         num_waves = degrees.shape[0]
         tiles = np.arange(num_waves) // max(waves_per_tile, 1)
         return counters_mod.WaveTrace(

@@ -65,10 +65,27 @@ def committed_id_stream(ids, num_segments: int, *,
     ids = np.asarray(ids).astype(np.int32).reshape(-1)
     pad = (-ids.shape[0]) % tile
     if pad:
-        seg_blocks = -(-num_segments // min(sk.DEFAULT_SEG_BLOCK, num_segments))
-        base = seg_blocks * min(sk.DEFAULT_SEG_BLOCK, num_segments)
-        sentinel = base + np.arange(pad, dtype=np.int32)
+        sentinel = _sentinel_base(num_segments) + np.arange(pad, dtype=np.int32)
         ids = np.concatenate([ids, sentinel]).astype(np.int32)
+    return ids
+
+
+def _sentinel_base(num_segments: int) -> int:
+    """The first id past the kernel's last segment block."""
+    block = min(sk.DEFAULT_SEG_BLOCK, num_segments)
+    return -(-num_segments // block) * block
+
+
+def committed_id_stream_device(ids: jnp.ndarray, num_segments: int, *,
+                               tile: int = sk.DEFAULT_TILE) -> jnp.ndarray:
+    """``committed_id_stream`` inside a jitted program, for ids made on the
+    device: the same flat int32 stream with the same sentinels."""
+    ids = ids.reshape(-1).astype(jnp.int32)
+    pad = (-ids.shape[0]) % tile
+    if pad:
+        sentinel = _sentinel_base(num_segments) + jnp.arange(pad,
+                                                            dtype=jnp.int32)
+        ids = jnp.concatenate([ids, sentinel])
     return ids
 
 
@@ -163,3 +180,50 @@ def instrumented_scatter_add(
         "trace": trace,
     }
     return out, counters
+
+
+@functools.lru_cache(maxsize=None)
+def count_program(stream_fn, num_segments: int, tile: int = sk.DEFAULT_TILE):
+    """One jitted device program: ``stream_fn(*args)`` makes an id stream
+    on the device, which is committed as ``committed_id_stream`` would and
+    counted by the instrumented scatter-add with unit values.
+
+    The program returns ``(counts (num_segments,), packed)``, where
+    ``packed`` holds the per-wave degrees and, last, the largest count as
+    a share of the stream's ids, so one read-back brings both.  Cached per
+    ``stream_fn`` (hold one function per stream, e.g.
+    ``repro.models.moe.expert_stream``), so each shape compiles once.
+    """
+    def _routed_count(*args):
+        ids = stream_fn(*args)
+        stream = committed_id_stream_device(ids, num_segments, tile=tile)
+        ones = jnp.ones((stream.shape[0], 1), jnp.float32)
+        counts, deg = sk.scatter_add_pallas(ones, stream, num_segments,
+                                            tile=tile, instrumented=True)
+        counts = counts[:, 0]
+        share = jnp.max(counts) / ids.size
+        return counts, jnp.concatenate([deg, share[None]])
+
+    return jax.jit(_routed_count)
+
+
+def instrumented_count(program, *args, num_cores: int = 8,
+                       job_class: int = timing.FAO,
+                       waves_per_tile: int | None = None,
+                       pipeline_depth: int = 2,
+                       tile: int = sk.DEFAULT_TILE):
+    """Launch a ``count_program`` on device-resident ``args``; nothing is
+    copied to the device and only the degrees come back.
+
+    Returns ``(counts on the device, counters)`` as
+    ``instrumented_scatter_add`` does, with ``max_load_share``: the
+    largest count over the stream's ids.
+    """
+    counts, packed = instr.launch(program, *args)
+    deg, share = packed[:-1], float(packed[-1])
+    trace = instr.wave_trace(
+        deg, job_class=job_class, num_cores=num_cores,
+        waves_per_tile=waves_per_tile or default_waves_per_tile(tile),
+        pipeline_depth=pipeline_depth, max_load_share=share)
+    return counts, {"N": float(deg.shape[0]), "O": float(deg.sum()),
+                    "degree": deg, "trace": trace, "max_load_share": share}

@@ -5,7 +5,8 @@ Distribution design (DESIGN.md §5): tokens stay resident on their
 over the ``model`` axis and replicated over data.  Inside a shard_map the
 layer (per data shard):
 
-  1. routes tokens (softmax top-k),
+  1. routes tokens (softmax top-k, or DeepSeek-V3's group-limited
+     sigmoid top-k: ``MoEConfig.scoring``),
   2. sorts the (token, expert-slot) stream by expert id — a *local* sort,
   3. counts tokens per expert with a bincount — **the paper's histogram**:
      the dispatch count's conflict structure is data-dependent (a
@@ -51,12 +52,46 @@ class MoEConfig:
                                     # halves the TP-psum/a2a wire traffic;
                                     # slots are write-once so the scatter
                                     # loses no precision
+    # router: "softmax" (top-k of softmax probabilities) or "sigmoid"
+    # (DeepSeek-V3's noaux_tc: see ``route``)
+    scoring: str = "softmax"
+    n_group: int = 1                # sigmoid: expert groups
+    topk_group: int = 1             # sigmoid: groups kept per token
+    routed_scaling_factor: float = 1.0
 
     @property
     def use_ep(self) -> bool:
         """Whole-expert EP (all_to_all) for big expert counts; the small-E
         archs keep experts replicated over data and TP-shard the hidden."""
         return self.num_experts >= 64
+
+    def __post_init__(self) -> None:
+        if self.scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown router scoring {self.scoring!r}")
+        if self.scoring == "sigmoid":
+            per_group, rem = divmod(self.num_experts, self.n_group)
+            if rem or per_group < 2:
+                raise ValueError(
+                    f"{self.num_experts} experts do not split into "
+                    f"{self.n_group} groups of at least 2")
+            if not 1 <= self.topk_group <= self.n_group or \
+                    self.top_k > self.topk_group * per_group:
+                raise ValueError(
+                    f"top-{self.top_k} of {self.topk_group} kept groups of "
+                    f"{per_group} experts is not a selection")
+
+
+def init_router(key, cfg: MoEConfig) -> dict:
+    """The router's parameters: the (d_model, E) gate weight at the dense
+    init, and for sigmoid scoring DeepSeek-V3's float32 gate and its
+    ``e_score_correction_bias`` (zeros: the training loop's balancing
+    update moves it from the expert-load count)."""
+    if cfg.scoring == "softmax":
+        return layers.dense_init(key, cfg.d_model, cfg.num_experts,
+                                 jnp.dtype(cfg.dtype))
+    p = layers.dense_init(key, cfg.d_model, cfg.num_experts, jnp.float32)
+    p["bias"] = jnp.zeros((cfg.num_experts,), jnp.float32)
+    return p
 
 
 def init(key, cfg: MoEConfig) -> dict:
@@ -65,7 +100,7 @@ def init(key, cfg: MoEConfig) -> dict:
     scale_in = cfg.d_model ** -0.5
     scale_out = cfg.d_expert ** -0.5
     p = {
-        "router": layers.dense_init(kr, cfg.d_model, cfg.num_experts, dt),
+        "router": init_router(kr, cfg),
         "w_gate": layers.truncated_normal_init(
             k1, (cfg.num_experts, cfg.d_model, cfg.d_expert), scale_in, dt),
         "w_up": layers.truncated_normal_init(
@@ -82,6 +117,8 @@ def init(key, cfg: MoEConfig) -> dict:
 
 def route(p: dict, x: jnp.ndarray, cfg: MoEConfig):
     """Router: returns (gates (T,k) f32, ids (T,k) i32, aux_loss scalar)."""
+    if cfg.scoring == "sigmoid":
+        return _route_noaux_tc(p["router"], x, cfg)
     logits = (x.astype(jnp.float32) @ p["router"]["w"].astype(jnp.float32))
     probs = jax.nn.softmax(logits, axis=-1)
     gates, ids = jax.lax.top_k(probs, cfg.top_k)
@@ -93,6 +130,51 @@ def route(p: dict, x: jnp.ndarray, cfg: MoEConfig):
     mean_probs = probs.mean(axis=tuple(range(probs.ndim - 1)))
     aux = cfg.num_experts * jnp.sum(density * mean_probs)
     return gates, ids, aux
+
+
+def _route_noaux_tc(router: dict, x: jnp.ndarray, cfg: MoEConfig):
+    """DeepSeek-V3's router (``scoring_func`` sigmoid, ``topk_method``
+    noaux_tc).
+
+    The logits are computed in float32 at full precision, as the public
+    modelling code casts the hidden states and the gate to float32.
+    Experts are *selected* on the sigmoid score plus the learned
+    ``e_score_correction_bias``: a group scores the sum of its two best
+    biased scores, the best ``topk_group`` of the ``n_group`` groups are
+    kept, and the ``top_k`` best biased scores inside them are taken.  The
+    *gates* are the unbiased scores of those experts, normalised (the
+    published ``norm_topk_prob`` is true) and scaled by
+    ``routed_scaling_factor``.  Ties go to
+    the lower index, as ``jax.lax.top_k`` breaks them.  Experts of dropped
+    groups are masked with -inf (the public code fills 0.0; the two agree
+    whenever the kept groups hold ``top_k`` experts of positive score).
+    Balancing is the bias's job, so the auxiliary loss is 0.
+    """
+    t = x.shape[0]
+    logits = jnp.matmul(x.astype(jnp.float32),
+                        router["w"].astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    choice = scores + router["bias"].astype(jnp.float32)
+    grouped = choice.reshape(t, cfg.n_group, -1)
+    group_score = jax.lax.top_k(grouped, 2)[0].sum(-1)          # (T, G)
+    _, keep = jax.lax.top_k(group_score, cfg.topk_group)
+    kept = (keep[:, :, None] == jnp.arange(cfg.n_group)).any(1)  # (T, G)
+    masked = jnp.where(kept[:, :, None], grouped, -jnp.inf).reshape(t, -1)
+    _, ids = jax.lax.top_k(masked, cfg.top_k)
+    gates = jnp.take_along_axis(scores, ids, axis=-1)
+    gates = gates / (gates.sum(-1, keepdims=True) + 1e-20)
+    return gates * cfg.routed_scaling_factor, ids, jnp.zeros((), jnp.float32)
+
+
+@functools.lru_cache(maxsize=None)
+def expert_stream(cfg: MoEConfig):
+    """``(x, router) -> ids (T, k)``: the router's expert choices for one
+    batch, the stream the expert-load count commits token-major.  One
+    function per configuration, so a jitted caller compiles it once."""
+    def stream(x, router):
+        return route({"router": router}, x, cfg)[1]
+    return stream
 
 
 def _expert_ffn_sorted(p: dict, xs: jnp.ndarray, group_sizes: jnp.ndarray,
@@ -254,7 +336,7 @@ def apply_ep(p: dict, x: jnp.ndarray, cfg: MoEConfig, mesh,
         return out.reshape(bl, sl, d), aux, disp
 
     pspec = {
-        "router": {"w": P()},
+        "router": {k: P() for k in p["router"]},
         "w_gate": P(ep_axis, None, tp_axis),
         "w_up": P(ep_axis, None, tp_axis),
         "w_down": P(ep_axis, tp_axis, None),
@@ -290,7 +372,7 @@ def apply_sharded(p: dict, x: jnp.ndarray, cfg: MoEConfig, mesh,
         return out.reshape(bl, sl, d), aux, disp
 
     pspec = {
-        "router": {"w": P()},
+        "router": {k: P() for k in p["router"]},
         "w_gate": P(None, None, tp_axis),
         "w_up": P(None, None, tp_axis),
         "w_down": P(None, tp_axis, None),

@@ -97,7 +97,10 @@ def _moe_cfg(cfg: ModelConfig) -> moe.MoEConfig:
         num_shared_experts=cfg.num_shared_experts,
         activation=cfg.activation, dtype=cfg.dtype,
         capacity_factor=cfg.moe_capacity_factor,
-        bf16_combine=cfg.moe_bf16_combine)
+        bf16_combine=cfg.moe_bf16_combine,
+        scoring=cfg.moe_scoring, n_group=cfg.moe_n_group,
+        topk_group=cfg.moe_topk_group,
+        routed_scaling_factor=cfg.moe_routed_scaling_factor)
 
 
 # ---------------------------------------------------------------------------

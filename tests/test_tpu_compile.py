@@ -11,7 +11,9 @@ the TPU library.  All of these tests live in this one file for that reason.
 The sizes are the chip smoke test's: the paper's largest histogram image
 (RGBA, 2^22 px), the MoE dispatch of a 128-expert top-8 router over 8,192
 tokens (65,536 ids), a segment sum over 16,384 segments (four segment
-blocks) at D=128, and flash attention at (8, 2048, 128) bf16.
+blocks) at D=128, and flash attention at (8, 2048, 128) bf16; and the
+dsv3-routed cell's two programs, DeepSeek-V3's router with its count and
+the device digest of its 16,384-token batch.
 """
 
 import os
@@ -109,3 +111,31 @@ def test_flash_attention_compiles(compile_for_chip):
 
     qkv = ((8, 2048, 128), jnp.bfloat16)
     _check(compile_for_chip(fk.flash_attention_pallas, qkv, qkv, qkv))
+
+
+def test_routed_count_program_compiles(compile_for_chip):
+    """DeepSeek-V3's router and the expert-load count in one program, at
+    the published widths: 16,384 bf16 tokens of 7,168, 256 experts in 8
+    groups, top-8 of the best 4 (131,072 ids)."""
+    from repro.kernels.scatter_add import ops
+    from repro.models import moe
+
+    cfg = moe.MoEConfig(d_model=7168, d_expert=2048, num_experts=256,
+                        top_k=8, scoring="sigmoid", n_group=8, topk_group=4,
+                        routed_scaling_factor=2.5)
+    program = ops.count_program(moe.expert_stream(cfg), 256)
+    compiled = compile_for_chip(
+        lambda x, w, b: program(x, {"w": w, "bias": b}),
+        ((16384, 7168), jnp.bfloat16), ((7168, 256), jnp.float32),
+        ((256,), jnp.float32))
+    _check(compiled)
+
+
+def test_device_digest_compiles(compile_for_chip):
+    from repro.kernels import digest
+
+    compiled = compile_for_chip(
+        lambda x, w, b: digest._digest_all((x, w, b)),
+        ((16384, 7168), jnp.bfloat16), ((7168, 256), jnp.float32),
+        ((256,), jnp.float32))
+    assert compiled.memory_analysis().temp_size_in_bytes < HBM_BYTES
