@@ -7,10 +7,10 @@ different sub-histogram banks.
 
 TPU adaptation: there is no atomic unit; the idiomatic TPU histogram keeps
 the (channels x bins) accumulator resident in VMEM across the grid (output
-block with a constant index_map) and commits each wave with a one-hot
-reduction.  The queuing model prices that commit as a unit that
-serializes duplicate destinations; whether this kernel's chip time
-follows it is an open question (``PERF.md``).  Two variants:
+block with a constant index_map) and commits each tile of the stream as
+one contraction of one-hots.  The queuing model prices that commit as a
+unit that serializes duplicate destinations; whether this kernel's chip
+time follows it is an open question (``PERF.md``).  Two variants:
 
   * ``hist``   — channels processed in natural order (Listing 1): a
     solid-color tile drives every lane of a wave into one bin.
@@ -25,13 +25,22 @@ the instrumented variants measure in-kernel (``instrumentation.py``).
 Block layout: the wrapper lays the image out in commit order before the
 launch (``commit_layout``): a lane-dense ``(N*C/128, 128)`` array whose
 row-major order is the committed stream, four 32-lane commit groups per
-row.  Each grid step streams one wave (8 rows = 1024 commits) HBM->VMEM;
-the kernel adds the channel offsets from lane iotas, commits the wave as
-one one-hot popcount, and keeps a ``(C*num_bins, 128)`` per-lane
-accumulator resident in VMEM (constant index_map) for the whole launch —
-the scratchpad residency pattern the paper's kernels use shared memory
-for.  The wrapper sums the 128 lane partials.  Nothing inside the kernel
-changes the shape of the stream, so Mosaic compiles it for the TPU.
+row.  Each grid step streams one tile (``tile*C/128`` rows, several
+waves) HBM->VMEM and adds the channel offsets from lane iotas.  The
+commit factors each channel-offset bin ``b`` as ``(b // FACTOR,
+b % FACTOR)``: two narrow one-hots, ``(rows, hi_bins, 128)`` and
+``(rows, FACTOR, 128)`` in bfloat16, contracted over the lanes on the MXU
+(batched over rows, float32 results) give every row's joint counts, and
+their sum over rows is the tile's ``(hi_bins, FACTOR)`` histogram, which
+is added into an int32 accumulator resident in VMEM (constant index_map)
+for the whole launch — the scratchpad residency pattern the paper's
+kernels use shared memory for.  Counts stay exact: 0 and 1 are exact in
+bfloat16, a step adds at most ``rows*128`` to a cell (far below 2^24),
+and the sum across steps is int32.  The wrapper reads the accumulator
+back as ``(C, num_bins)``.  Nothing inside the kernel changes the shape
+of the stream, so Mosaic compiles it for the TPU.  The weighted kernel
+keeps a per-wave one-hot reduction on the VPU: its float32 weights would
+be rounded on the MXU.
 """
 
 from __future__ import annotations
@@ -46,6 +55,7 @@ from repro import kernels
 from repro.kernels import instrumentation as instr
 
 DEFAULT_TILE = 2048
+FACTOR = 32         # bins per lo one-hot of the factored commit
 
 
 def commit_layout(img: jnp.ndarray, *, reorder: bool,
@@ -98,24 +108,43 @@ def _onehot(bins: jnp.ndarray, total_bins: int) -> jnp.ndarray:
         jnp.int32, (r, total_bins, w), 1)
 
 
+def _hi_bins(total_bins: int) -> int:
+    """Rows of the factored accumulator: ``ceil(total_bins / FACTOR)``,
+    padded to a multiple of 8 sublanes."""
+    return -(-total_bins // (FACTOR * 8)) * 8
+
+
+def _factored_counts(bins: jnp.ndarray, num_hi: int) -> jnp.ndarray:
+    """(num_hi, FACTOR) int32 counts of a (rows, ROW) bin block.
+
+    Row ``r``'s one-hots of ``bins // FACTOR`` and ``bins % FACTOR``,
+    contracted over the lanes, count its lanes per (hi, lo) pair; the sum
+    over rows is the block's histogram of ``hi * FACTOR + lo = bins``.
+    """
+    hi = _onehot(jax.lax.div(bins, FACTOR), num_hi).astype(jnp.bfloat16)
+    lo = _onehot(jax.lax.rem(bins, FACTOR), FACTOR).astype(jnp.bfloat16)
+    joint = jax.lax.dot_general(
+        hi, lo, dimension_numbers=(((2,), (2,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32)
+    return joint.sum(axis=0).astype(jnp.int32)
+
+
 def _hist_kernel(vals_ref, out_ref, deg_ref=None, *, num_bins: int,
                  channels: int, reorder: bool):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
+    @pl.when(pl.program_id(0) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
     bins = _commit_bins(vals_ref[...], num_bins=num_bins,
                         channels=channels, reorder=reorder)
-    onehot = _onehot(bins, out_ref.shape[0])
-    out_ref[...] += onehot.astype(jnp.int32).sum(axis=0)
+    out_ref[...] += _factored_counts(bins, out_ref.shape[0])
     if deg_ref is not None:
-        # one wave per step: lane (i % waves_per_tile) of this tile's block
-        (deg,) = instr.wave_degrees(bins)
+        # one tile per step: its block holds a degree per wave, in order
         lane = jax.lax.broadcasted_iota(jnp.int32, deg_ref.shape, 2)
-        wave = jax.lax.rem(i, deg_ref.shape[2])
-        deg_ref[...] = jnp.where(lane == wave, deg, deg_ref[...])
+        deg = jnp.zeros(deg_ref.shape, jnp.float32)
+        for w, d in enumerate(instr.wave_degrees(bins)):
+            deg = jnp.where(lane == w, d, deg)
+        deg_ref[...] = deg
 
 
 def _hist_weighted_kernel(vals_ref, w_ref, out_ref, *, num_bins: int,
@@ -152,40 +181,46 @@ def histogram_pallas(
     assert (instr.ROW // instr.COMMIT_GROUP) % c == 0, \
         "a stream row must hold whole channel rounds"
     waves_per_tile = (tile * c) // instr.LANES
-    grid = (n * c // instr.LANES,)
     total_bins = c * num_bins
     vals = commit_layout(img, reorder=reorder, tile=tile)
-    wave_spec = pl.BlockSpec((instr.WAVE_ROWS, instr.ROW), lambda i: (i, 0))
-    out_spec = pl.BlockSpec((total_bins, instr.ROW), lambda i: (0, 0))
     params = dict(num_bins=num_bins, channels=c, reorder=reorder)
-
-    def per_channel(lane_partials):
-        return lane_partials.sum(axis=1).reshape(c, num_bins)
 
     if weights is not None:
         w = commit_layout(jnp.broadcast_to(weights[:, None], (n, c)),
                           reorder=False, tile=tile)
+        wave_spec = pl.BlockSpec((instr.WAVE_ROWS, instr.ROW),
+                                 lambda i: (i, 0))
         out = pl.pallas_call(
             functools.partial(_hist_weighted_kernel, **params),
-            grid=grid,
+            grid=(n * c // instr.LANES,),
             in_specs=[wave_spec, wave_spec],
-            out_specs=out_spec,
+            out_specs=pl.BlockSpec((total_bins, instr.ROW),
+                                   lambda i: (0, 0)),
             out_shape=jax.ShapeDtypeStruct((total_bins, instr.ROW),
                                            jnp.float32),
             interpret=kernels.interpret_mode(),
         )(vals, w)
-        return per_channel(out)
+        return out.sum(axis=1).reshape(c, num_bins)
+
+    grid = (n // tile,)
+    tile_spec = pl.BlockSpec((tile * c // instr.ROW, instr.ROW),
+                             lambda i: (i, 0))
+    counts_shape = (_hi_bins(total_bins), FACTOR)
+    counts_spec = pl.BlockSpec(counts_shape, lambda i: (0, 0))
+    counts = jax.ShapeDtypeStruct(counts_shape, jnp.int32)
+
+    def per_channel(joint):
+        return joint.reshape(-1)[:total_bins].reshape(c, num_bins)
 
     if instrumented:
         out, deg = pl.pallas_call(
             functools.partial(_hist_kernel, **params),
             grid=grid,
-            in_specs=[wave_spec],
-            out_specs=[out_spec,
+            in_specs=[tile_spec],
+            out_specs=[counts_spec,
                        pl.BlockSpec((1, 1, waves_per_tile),
-                                    lambda i: (i // waves_per_tile, 0, 0))],
-            out_shape=[jax.ShapeDtypeStruct((total_bins, instr.ROW),
-                                            jnp.int32),
+                                    lambda i: (i, 0, 0))],
+            out_shape=[counts,
                        jax.ShapeDtypeStruct((n // tile, 1, waves_per_tile),
                                             jnp.float32)],
             interpret=kernels.interpret_mode(),
@@ -195,9 +230,9 @@ def histogram_pallas(
     out = pl.pallas_call(
         functools.partial(_hist_kernel, **params),
         grid=grid,
-        in_specs=[wave_spec],
-        out_specs=out_spec,
-        out_shape=jax.ShapeDtypeStruct((total_bins, instr.ROW), jnp.int32),
+        in_specs=[tile_spec],
+        out_specs=counts_spec,
+        out_shape=counts,
         interpret=kernels.interpret_mode(),
     )(vals)
     return per_channel(out)

@@ -3,8 +3,9 @@
 Instruction-class mapping (paper §2 / §4):
 
   * unweighted, result-unread  -> POPC class (Ampere's ``ATOMS.POPC.INC``:
-    the compiler's cheap population-count increment; our one-hot popcount
-    reduction is literally that operation),
+    the compiler's cheap population-count increment; our commit counts a
+    tile's values per bin with no result read back — the factored
+    one-hots of ``bin // 32`` and ``bin % 32`` contracted on the MXU),
   * unweighted, ``force_fao``  -> FAO class (the paper forces ``ATOMS.ADD``
     back with a dummy read of the atomic's result),
   * weighted (f32 accumulate)  -> CAS class (FP atomics lower to

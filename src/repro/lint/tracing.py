@@ -10,6 +10,8 @@ inner jaxpr with an abstract interpreter over the expression language in
 * the one-hot scatter idiom — ``eq(stream[:, None], iota(dim=1))``
   reduced with ``reduce_sum`` (popcount/histogram) or contracted with
   ``dot_general`` (row scatter-add) and accumulated into an output ref;
+  two one-hots of a factored bin id (``b // F``, ``b % F``) contracted
+  over their tokens are one popcount of ``b``;
 * ``pl.when(pl.program_id(a) == 0)`` init guards around zero stores;
 * read-modify-write accumulation (``get`` → combine → ``swap`` on the
   same ref) and retry loops (``while`` bodies containing ``swap``).
@@ -319,6 +321,48 @@ def _onehot_from_eq(lhs: SymVal, rhs: SymVal, out_shape) -> Optional[OneHotTag]:
     return None
 
 
+def _factored_popcount(lhs: SymVal, rhs: SymVal,
+                       dimension_numbers) -> Optional[AccumTag]:
+    """The popcount site of a product of two one-hots over their tokens.
+
+    ``dot_general(onehot(hi), onehot(lo))`` that contracts or batches
+    every token axis of both operands, pairing them in stream order,
+    counts each token once at ``(hi, lo)``: a histogram of the stream
+    ``hi * lo_bins + lo`` over ``hi_bins * lo_bins`` bins (the histogram
+    kernel's factored commit).  None for any other product.
+    """
+    (lc, rc), (lb, rb) = dimension_numbers
+    lhs_paired, rhs_paired = tuple(lb) + tuple(lc), tuple(rb) + tuple(rc)
+    for lt in lhs.tags:
+        for rt in rhs.tags:
+            if not (isinstance(lt, OneHotTag) and isinstance(rt, OneHotTag)
+                    and lt.stream.shape == rt.stream.shape):
+                continue
+            lhs_tokens = [a for a in range(len(lhs.expr.shape))
+                          if a != lt.bin_axis]
+            rhs_tokens = [a for a in range(len(rhs.expr.shape))
+                          if a != rt.bin_axis]
+            if sorted(lhs_paired) != lhs_tokens \
+                    or sorted(rhs_paired) != rhs_tokens:
+                continue
+            if any(lhs_tokens.index(a) != rhs_tokens.index(b)
+                   for a, b in zip(lhs_paired, rhs_paired)):
+                continue
+            shape, dtype = lt.stream.shape, lt.stream.dtype
+            scale = sym.Const(shape=(), dtype=dtype,
+                              value=np.asarray(rt.num_bins, dtype))
+            stream = sym.Elem(shape=shape, dtype=dtype, op="add", args=(
+                sym.Elem(shape=shape, dtype=dtype, op="mul",
+                         args=(lt.stream, scale)),
+                rt.stream))
+            onehot = OneHotTag(stream=stream, bin_axis=len(lb),
+                               num_bins=lt.num_bins * rt.num_bins,
+                               stream_len=lt.stream_len)
+            return AccumTag(onehot=onehot, kind="one_hot_popcount",
+                            row_elems=1)
+    return None
+
+
 def _contains_ref_read(expr: sym.Expr, ref: int) -> bool:
     return ref in sym.data_refs(expr)
 
@@ -606,6 +650,12 @@ class _Interpreter:
         lhs = self._read(env, eqn.invars[0])
         rhs = self._read(env, eqn.invars[1])
         out_shape, _ = _avals(eqn.outvars[0])
+        joint = _factored_popcount(lhs, rhs,
+                                   eqn.params["dimension_numbers"])
+        if joint is not None:
+            self._opaque_outs(env, eqn, reason="dot_general",
+                              tags=frozenset({joint}))
+            return
         tags = set()
         for tag in lhs.tags | rhs.tags:
             if isinstance(tag, OneHotTag):

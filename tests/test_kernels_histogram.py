@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.core import timing
+from repro.core import counters, timing
 from repro.kernels.histogram import ops, ref
 
 
@@ -20,6 +20,59 @@ def test_histogram_matches_ref(n_pixels, variant, dtype):
     expect = ref.histogram_ref(jnp.asarray(img.astype(np.int32)))
     np.testing.assert_array_equal(np.asarray(out), np.asarray(expect))
     assert int(out.sum()) == n_pixels * 4
+
+
+def _image(kind: str, n_pixels: int, num_bins: int) -> np.ndarray:
+    rng = np.random.default_rng(3)
+    if kind == "uniform":
+        return rng.integers(0, num_bins, (n_pixels, 4)).astype(np.int32)
+    img = np.empty((n_pixels, 4), np.int32)
+    img[:] = rng.integers(0, num_bins, 4)
+    if kind == "noise":       # solid, with a few 32-pixel noise blocks
+        blocks = rng.choice(n_pixels // 32, 5, replace=False)
+        img[:n_pixels // 32 * 32].reshape(-1, 32, 4)[blocks] = \
+            rng.integers(0, num_bins, (5, 32, 4))
+    return img
+
+
+# (image, variant, num_bins, tile): both launches of the factored commit
+# against ref.py, over hi axes of 32 (256 bins), 8 (64) and 13 -> 16 (100)
+_COMMIT_CASES = (
+    [(k, v, 256, 2048) for k in ("solid", "noise", "uniform")
+     for v in ("hist", "hist2")]
+    + [(k, v, 256, 256) for k in ("noise", "uniform")
+       for v in ("hist", "hist2")]
+    + [("uniform", v, nb, 2048) for nb in (64, 100) for v in ("hist", "hist2")]
+    + [("solid", "hist", 100, 256)])
+
+
+@pytest.mark.parametrize("kind,variant,num_bins,tile", _COMMIT_CASES)
+def test_factored_commit_matches_ref_and_degrees(kind, variant, num_bins,
+                                                 tile):
+    img = _image(kind, 6000, num_bins)       # not a tile multiple: padded
+    expect = np.asarray(ref.histogram_ref(jnp.asarray(img), num_bins))
+    out = ops.histogram(jnp.asarray(img), num_bins=num_bins,
+                        variant=variant, tile=tile)
+    np.testing.assert_array_equal(np.asarray(out), expect)
+    hist, deg = ops._histogram_and_degrees(
+        jnp.asarray(img), num_bins=num_bins, variant=variant, tile=tile)
+    np.testing.assert_array_equal(np.asarray(hist), expect)
+    stream = ops.committed_index_stream(img, num_bins=num_bins,
+                                        variant=variant, tile=tile)
+    waves = stream.reshape(-1, 1024)
+    assert deg.shape == (waves.shape[0],)
+    np.testing.assert_array_equal(
+        np.asarray(deg), [counters.wave_degree(w) for w in waves])
+
+
+def test_factored_commit_exact_at_full_step():
+    """A one-channel solid image at a tile of 8192 puts every value of a
+    step, 8192 counts, into one cell, step after step."""
+    img = np.full((8192 * 6, 1), 77, np.int32)
+    out = np.asarray(ops.histogram(jnp.asarray(img), tile=8192))
+    expect = np.zeros((1, 256), np.int64)
+    expect[0, 77] = img.shape[0]
+    np.testing.assert_array_equal(out, expect)
 
 
 @pytest.mark.parametrize("variant", ["hist", "hist2"])

@@ -19,6 +19,7 @@ from repro.analysis import Session, WorkloadSpec
 from repro.analysis.providers.trace import TraceProvider
 from repro.core import timing
 from repro.data.images import make_image
+from repro.kernels.histogram import kernel as hist_kernel
 from repro.kernels.histogram import ops as hist_ops
 from repro.lint import registry as lint_registry_mod
 from repro.lint import symbolic
@@ -94,13 +95,59 @@ def test_hist_kernel_model_structure():
     models = analyze_callable(target.fn, *target.args, name="hist")
     assert len(models) == 1
     m = models[0]
-    # one step per wave: 1024 commits = 256 px x 4 channels
-    assert m.grid == (PROBE_PIXELS * 4 // 1024,)
+    # one step per tile of 2048 px (8 waves of 1024 commits)
+    assert m.grid == (PROBE_PIXELS // hist_kernel.DEFAULT_TILE,)
     site = m.sites[0]
     assert site.kind == "one_hot_popcount"
     assert site.rmw and site.num_bins == 1024 and site.row_elems == 1
     # the @pl.when(pid==0) zero-init is seen as an init guard on axis 0
     assert m.init_guards.get(site.ref) == {0}
+
+
+def test_factored_one_hot_product_is_one_popcount_site(sess):
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    def kernel(x_ref, o_ref):
+        @pl.when(pl.program_id(0) == 0)
+        def _():
+            o_ref[...] = jnp.zeros_like(o_ref)
+
+        b = x_ref[...]                       # (8, 128) bin ids in [0, 64)
+        iota = jax.lax.broadcasted_iota(jnp.int32, (8, 8, 128), 1)
+        hi = (jax.lax.div(b, 8)[:, None, :] == iota).astype(jnp.bfloat16)
+        lo = (jax.lax.rem(b, 8)[:, None, :] == iota).astype(jnp.bfloat16)
+        joint = jax.lax.dot_general(
+            hi, lo, dimension_numbers=(((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)
+        o_ref[...] += joint.sum(axis=0).astype(jnp.int32)
+
+    def launch(x):
+        return pl.pallas_call(
+            kernel,
+            grid=(2,),
+            in_specs=[pl.BlockSpec((8, 128), lambda i: (i, 0))],
+            out_specs=pl.BlockSpec((8, 8), lambda i: (0, 0)),
+            out_shape=jax.ShapeDtypeStruct((8, 8), jnp.int32),
+            interpret=True,
+        )(x)
+
+    x = (np.arange(16 * 128, dtype=np.int32) * 7 % 64).reshape(16, 128)
+    models = analyze_callable(launch, x, name="factored")
+    (site,) = models[0].sites
+    assert site.kind == "one_hot_popcount" and site.row_elems == 1
+    assert site.num_bins == 64 and site.stream_len == 8 * 128
+    # hi * 8 + lo is the bin id itself, element for element
+    np.testing.assert_array_equal(
+        symbolic.evaluate(site.stream, {("ref", 0): x[8:]}), x[8:])
+    np.testing.assert_array_equal(
+        np.asarray(launch(x)).reshape(-1), np.bincount(x.reshape(-1)))
+    target = lint_mod.LintTarget(
+        label="factored", fn=launch, args=(x,), operands=(x,),
+        spec=None, module=None, job_class=timing.POPC, waves_per_tile=1)
+    findings = lint_mod.evaluate_target(target, sess, models=models)
+    assert not any(f.rule_id == "KERN002" for f in findings), findings
 
 
 def test_unguarded_accumulation_fires_kern003(sess):
