@@ -14,8 +14,7 @@ re-advised spec collects nothing), and **each frontier is scored by a
 single columnar ``CounterFrame``/``profile_batch`` evaluation** — the
 baseline rides along as row 0, so predicted speedups come from one
 whole-array model pass per level, never per-candidate scalar profiling.
-That batch-evaluation invariant is asserted by tests and the
-``advise_search`` benchmark gate.
+That batch-evaluation invariant is asserted by tests.
 """
 
 from __future__ import annotations

@@ -1,8 +1,8 @@
 """InstrumentedKernelProvider: the measured counter path.
 
-Launches the instrumented Pallas kernel described by the spec and reads
-the in-kernel ``wave_degrees`` counters back (via the kernel families'
-``collect_counters()`` hooks) — nothing is synthesized on the host.
+Launches the instrumented Pallas kernel described by the spec
+(``WorkloadSpec.run_kernel``) and reads the in-kernel ``wave_degrees``
+counters back — nothing is synthesized on the host.
 This is the paper's "measured" column.  On a TPU the kernel is compiled
 by Mosaic and runs on the chip; on any other backend it runs in the
 Pallas interpreter (the CPU test path) and counts the same stream.
@@ -12,9 +12,9 @@ Every ``CounterSet`` records which it was (``meta``: ``platform``,
 models: a chip of another registered kind, or of a kind the registry
 does not know, raises — no rates are assumed for it.
 
-``indices`` sources are routed through the instrumented scatter-add
-kernel (the index stream becomes a unit-value scatter), so even synthetic
-streams can be cross-validated against in-kernel counters.
+``indices`` sources run through the instrumented scatter-add kernel (the
+index stream becomes a unit-value scatter), so even synthetic streams can
+be cross-validated against in-kernel counters.
 """
 
 from __future__ import annotations
@@ -76,14 +76,11 @@ class InstrumentedKernelProvider:
         return dataclasses.replace(cset, meta={**cset.meta, **info})
 
     def _collect(self, spec) -> CounterSet:
-        if spec.kernel is not None:
+        if spec.kernel is not None or spec.indices is not None:
             # spec.run_kernel() owns the op dispatch and geometry
-            # threading (one definition, shared with resolve_trace); the
-            # per-family ops also expose collect_counters() hooks for
-            # direct low-level use outside a Session.
-            tr, meta = spec.run_kernel(), {"op": spec.kernel.op}
-        elif spec.indices is not None:
-            return self._collect_indices(spec)
+            # threading (one definition, shared with resolve_trace)
+            op = "scatter_add" if spec.kernel is None else spec.kernel.op
+            tr, meta = spec.run_kernel(), {"op": op}
         elif spec.run is not None:
             # custom lazy source: by contract it runs an instrumented
             # kernel and returns its trace
@@ -99,39 +96,6 @@ class InstrumentedKernelProvider:
                 bytes_read=spec.bytes_read, flops=spec.flops,
                 overhead_cycles=spec.overhead_cycles, source=self.name,
                 meta=meta)
-
-    def _collect_indices(self, spec) -> CounterSet:
-        """Run a bare index stream through the instrumented scatter-add.
-
-        Geometry defaults mirror ``trace_from_indices`` (waves_per_tile 1)
-        so the 'trace' and 'kernel' providers agree bit-for-bit.  The
-        stream length must be a multiple of the kernel tile: a shorter
-        stream would be sentinel-padded by the launch, and the padding
-        waves would be *counted* — the measured N/e would then silently
-        diverge from the trace provider's (which models the raw stream),
-        turning every ``validate()`` into a false alarm.  Refuse instead.
-        """
-        import numpy as np
-
-        from repro.kernels.scatter_add import ops as scat_ops  # lazy: jax
-
-        idx = np.asarray(spec.indices).reshape(-1)
-        tile = scat_ops.sk.DEFAULT_TILE
-        if idx.size % tile != 0:
-            raise ValueError(
-                f"WorkloadSpec {spec.label!r}: the 'kernel' provider needs "
-                f"an index stream sized to a multiple of the scatter tile "
-                f"({tile}); got {idx.size}. Pad the stream, or use "
-                f"WorkloadSpec.from_scatter_add (both providers then share "
-                f"the kernel's own sentinel padding).")
-        return scat_ops.collect_counters(
-            idx, np.ones(idx.shape, np.float32), spec.num_bins,
-            label=spec.label, num_cores=spec.num_cores,
-            job_class=spec.job_class,
-            waves_per_tile=spec.waves_per_tile or 1,
-            pipeline_depth=spec.pipeline_depth or 2,
-            bytes_read=spec.bytes_read, flops=spec.flops,
-            overhead_cycles=spec.overhead_cycles)
 
 
 register_provider(InstrumentedKernelProvider())

@@ -4,17 +4,15 @@ This is the acquisition backend the pre-provider ``Session`` hardwired:
 counters derived from a wave trace built on the host.  For ``indices``
 and ``trace`` sources that is exactly the old behaviour; for ``kernel``
 sources it *synthesizes the kernel's committed index stream in numpy*
-(``committed_index_stream`` mirrors the in-kernel issue ordering bit for
-bit) instead of launching the kernel — the "modeled" column of the
-paper's §5 model-vs-measured validation, and orders of magnitude faster
-than a Pallas interpret run on the CPU.
+(``WorkloadSpec.committed_stream``, which mirrors the in-kernel issue
+ordering bit for bit) instead of launching the kernel — the "modeled"
+column of the paper's §5 model-vs-measured validation, and orders of
+magnitude faster than a Pallas interpret run on the CPU.
 """
 
 from __future__ import annotations
 
 from typing import Optional, Sequence
-
-import numpy as np
 
 from repro.analysis.providers.base import register_provider
 from repro.core import counters as counters_mod
@@ -39,16 +37,16 @@ class TraceProvider:
         """Vectorized batch collection: one frame row per spec.
 
         Every spec whose counters come from a committed index stream
-        (``indices`` sources and ``kernel`` sources, whose streams the
-        kernel ops synthesize in numpy) is routed through
-        ``traces_from_index_batch`` and the stacked per-core aggregation
-        of ``countersets_from_traces``, so the whole grid's wave degrees
-        AND counter bundles come out of a few large numpy ops.
+        (``indices`` and ``kernel`` sources: ``spec.committed_stream()``)
+        is routed through ``traces_from_index_batch`` and the stacked
+        per-core aggregation of ``countersets_from_traces``, so the whole
+        grid's wave degrees AND counter bundles come out of a few large
+        numpy ops.
         Pre-recorded ``trace`` sources and opaque ``run`` callables keep
         the scalar path per point.  Rows are bit-for-bit equal to
         ``collect`` — neither the batch degree kernel nor the stacked
         aggregation ever mixes rows (asserted per provider by
-        ``Session.validate`` and the ``collect_batch_vs_loop`` canary).
+        ``Session.validate`` and the tier-1 tests).
         """
         del parallel  # the vectorized path has no per-point loop to thread
         specs = list(specs)
@@ -58,15 +56,10 @@ class TraceProvider:
         planned: list[int] = []
         streams, classes, wpts, depths, cores = [], [], [], [], []
         for i, spec in enumerate(specs):
-            if spec.kernel is not None:
-                stream, job_class, wpt = self._stream_plan(spec)
-            elif spec.indices is not None:
-                stream = np.asarray(spec.indices).reshape(-1)
-                job_class = spec.job_class
-                wpt = spec.waves_per_tile or 1
-            else:
+            if spec.kernel is None and spec.indices is None:
                 csets[i] = self.collect(spec, device)
                 continue
+            stream, job_class, wpt = spec.committed_stream()
             planned.append(i)
             streams.append(stream)
             classes.append(job_class)
@@ -89,25 +82,6 @@ class TraceProvider:
                 csets[i] = cs
         return CounterFrame.from_sets(csets)
 
-    def committed_stream(self, spec):
-        """(stream, job_class, waves_per_tile) for attributable specs.
-
-        The public stream-planning hook the observability layer rides
-        (``repro.obs.heatmap``): the exact committed index stream,
-        class, and geometry this provider feeds ``trace_from_indices``,
-        so per-bin attribution stays bit-consistent with ``collect``.
-        Sources that carry no index stream (pre-recorded ``trace``,
-        opaque ``run``, ``hlo``) cannot be attributed per bin.
-        """
-        if spec.kernel is not None:
-            return self._stream_plan(spec)
-        if spec.indices is not None:
-            return (np.asarray(spec.indices).reshape(-1),
-                    spec.job_class, spec.waves_per_tile or 1)
-        raise ValueError(
-            f"spec {spec.label!r} has no committed index stream to "
-            f"attribute (kernel/indices sources only)")
-
     def _from_trace(self, tr: counters_mod.WaveTrace, spec) -> CounterSet:
         """The one aggregation call both scalar and batch paths share."""
         return CounterSet.from_trace(
@@ -115,35 +89,9 @@ class TraceProvider:
             bytes_read=spec.bytes_read, flops=spec.flops,
             overhead_cycles=spec.overhead_cycles, source=self.name)
 
-    def _stream_plan(self, spec):
-        """(committed stream, job class, waves_per_tile) for a kernel spec.
-
-        The kernel family's committed-stream mirror makes the degrees
-        match the in-kernel instrumentation exactly (cross-validated by
-        the provider-equivalence tests and ``Session.validate``).
-        """
-        p = spec.kernel.params
-        if spec.kernel.op == "histogram":
-            from repro.kernels.histogram import ops as hist_ops  # lazy: jax
-            stream = hist_ops.committed_index_stream(
-                p["img"], num_bins=p["num_bins"], variant=p["variant"])
-            job_class = hist_ops.histogram_job_class(
-                force_fao=p["force_fao"], weighted=p["weighted"])
-            wpt = (spec.waves_per_tile
-                   or hist_ops.default_waves_per_tile(p["img"]))
-        elif spec.kernel.op == "scatter_add":
-            from repro.kernels.scatter_add import ops as scat_ops  # lazy
-            stream = scat_ops.committed_id_stream(
-                p["ids"], p["num_segments"])
-            job_class = p["job_class"]
-            wpt = spec.waves_per_tile or scat_ops.default_waves_per_tile()
-        else:
-            raise ValueError(f"unknown kernel op {spec.kernel.op!r}")
-        return stream, job_class, wpt
-
     def _synthesize(self, spec) -> counters_mod.WaveTrace:
         """Build the trace a kernel launch would emit, without launching."""
-        stream, job_class, wpt = self._stream_plan(spec)
+        stream, job_class, wpt = spec.committed_stream()
         # trace_from_indices' num_bins argument is unused (degrees come
         # from the raw index values); the spec default satisfies the
         # signature

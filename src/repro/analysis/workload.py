@@ -11,12 +11,12 @@ read, FLOPs, overhead).  Specs are frozen — sweeps derive variants with
 
 A spec is deliberately *provider-agnostic*: it describes the workload,
 not how its counters are acquired.  ``KernelSource`` keeps the kernel
-launch as data (op name + arguments) rather than a baked closure, so the
-``repro.analysis.providers`` backends can either synthesize the committed
-index stream in numpy (``TraceProvider``) or actually run the
-interpret-mode Pallas kernel (``InstrumentedKernelProvider``) from one
-and the same spec — the model-vs-measured split the paper's validation
-(§5) needs.
+launch as data (op name + arguments) rather than a baked closure, and the
+spec alone knows what each op runs and commits: ``committed_stream()``
+synthesizes the committed index stream in numpy (``TraceProvider``, the
+heat map) and ``run_kernel()`` launches the instrumented Pallas kernel
+(``InstrumentedKernelProvider``) from one and the same spec — the
+model-vs-measured split the paper's validation (§5) needs.
 """
 
 from __future__ import annotations
@@ -115,10 +115,11 @@ def _device_digests(arrays: list) -> list:
 class KernelSource:
     """A described (not yet launched) instrumented-kernel source.
 
-    ``op`` names the kernel family (``"histogram"`` | ``"scatter_add"``);
-    ``params`` holds its source-specific arguments (image / ids / values /
-    bins).  Launch geometry lives on the owning ``WorkloadSpec`` so
-    ``with_()`` derivations apply to the launch too.
+    ``op`` names the kernel family (``"histogram"`` | ``"scatter_add"`` |
+    ``"moe_router"``); ``params`` holds its source-specific arguments
+    (image / ids / values / bins / router).  Launch geometry lives on the
+    owning ``WorkloadSpec`` so ``with_()`` derivations apply to the launch
+    too.
     """
 
     op: str
@@ -298,7 +299,15 @@ class WorkloadSpec:
         return tr
 
     def run_kernel(self) -> counters_mod.WaveTrace:
-        """Launch the described instrumented kernel; return its trace."""
+        """Launch the described instrumented kernel; return its trace.
+
+        A bare ``indices`` stream runs through the instrumented scatter-add
+        as unit values, with the geometry defaults of
+        ``trace_from_indices`` (waves_per_tile 1), so the 'trace' and
+        'kernel' providers agree bit for bit.
+        """
+        if self.indices is not None:
+            return self._run_indices()
         if self.kernel is None:
             raise ValueError(f"WorkloadSpec {self.label!r} has no kernel "
                              f"source")
@@ -346,6 +355,75 @@ class WorkloadSpec:
             pipeline_depth=self.pipeline_depth or 2)
         _ROUTED_TOKENS.inc(p["hidden"].shape[0], layer=str(p["layer"]))
         return c["trace"]
+
+    def _run_indices(self) -> counters_mod.WaveTrace:
+        """Count the index stream with the instrumented scatter-add.
+
+        The stream length must be a multiple of the kernel tile: a shorter
+        stream would be sentinel-padded by the launch, and the padding
+        waves would be *counted* — the measured N/e would then silently
+        diverge from the trace provider's (which models the raw stream),
+        turning every ``validate()`` into a false alarm.  Refuse instead.
+        """
+        from repro.kernels.scatter_add import ops as scat_ops  # lazy: jax
+
+        idx = np.asarray(self.indices).reshape(-1)
+        tile = scat_ops.sk.DEFAULT_TILE
+        if idx.size % tile != 0:
+            raise ValueError(
+                f"WorkloadSpec {self.label!r}: the 'kernel' provider needs "
+                f"an index stream sized to a multiple of the scatter tile "
+                f"({tile}); got {idx.size}. Pad the stream, or use "
+                f"WorkloadSpec.from_scatter_add (both providers then share "
+                f"the kernel's own sentinel padding).")
+        _, c = scat_ops.instrumented_scatter_add(
+            idx, np.ones(idx.shape, np.float32), self.num_bins,
+            num_cores=self.num_cores, job_class=self.job_class,
+            waves_per_tile=self.waves_per_tile or 1,
+            pipeline_depth=self.pipeline_depth or 2)
+        return c["trace"]
+
+    def committed_stream(self) -> tuple[np.ndarray, int, int]:
+        """(committed index stream, job class, waves_per_tile).
+
+        The stream the trace provider synthesizes counters from and the
+        heat map attributes per bin.  For kernel sources it is the kernel
+        family's committed-stream mirror, so the degrees match the
+        in-kernel instrumentation exactly (cross-validated by the
+        provider-equivalence tests and ``Session.validate``).  Sources
+        with no host stream raise ``ValueError``: pre-recorded ``trace``,
+        opaque ``run``, HLO, and ``moe_router`` (its ids are made on the
+        device).
+        """
+        if self.indices is not None:
+            return (np.asarray(self.indices).reshape(-1), self.job_class,
+                    self.waves_per_tile or 1)
+        if self.kernel is None:
+            raise ValueError(
+                f"spec {self.label!r} has no committed index stream to "
+                f"attribute (kernel/indices sources only)")
+        p = self.kernel.params
+        if self.kernel.op == "histogram":
+            from repro.kernels.histogram import ops as hist_ops  # lazy: jax
+            stream = hist_ops.committed_index_stream(
+                p["img"], num_bins=p["num_bins"], variant=p["variant"])
+            job_class = hist_ops.histogram_job_class(
+                force_fao=p["force_fao"], weighted=p["weighted"])
+            return stream, job_class, (
+                self.waves_per_tile
+                or hist_ops.default_waves_per_tile(p["img"]))
+        if self.kernel.op == "scatter_add":
+            from repro.kernels.scatter_add import ops as scat_ops  # lazy
+            stream = scat_ops.committed_id_stream(
+                p["ids"], p["num_segments"])
+            return stream, p["job_class"], (
+                self.waves_per_tile or scat_ops.default_waves_per_tile())
+        if self.kernel.op == "moe_router":
+            raise ValueError(
+                f"spec {self.label!r}: a moe_router source routes its ids "
+                f"on the device, so it has no host index stream; collect "
+                f"it with the 'kernel' provider")
+        raise ValueError(f"unknown kernel op {self.kernel.op!r}")
 
     # -- constructors -----------------------------------------------------
 

@@ -25,7 +25,6 @@ from repro.core import counters as counters_mod
 from repro.core import timing
 from repro.kernels import instrumentation as instr
 from repro.kernels.histogram import kernel as hk
-from repro.obs import telemetry
 
 
 def _pad(img: jnp.ndarray, tile: int) -> tuple[jnp.ndarray, int]:
@@ -164,37 +163,3 @@ def committed_index_stream(img, *, num_bins: int = 256,
     bins = ch * num_bins + vals                           # (t, c) pixel-major
     bins = bins.reshape(t // g, g, c).transpose(0, 2, 1)  # step-major
     return bins.reshape(t * c)
-
-
-def collect_counters(
-    img,
-    *,
-    label: str = "",
-    num_bins: int = 256,
-    variant: str = "hist",
-    tile: int = hk.DEFAULT_TILE,
-    force_fao: bool = False,
-    weighted: bool = False,
-    num_cores: int = 8,
-    waves_per_tile: Optional[int] = None,
-    pipeline_depth: int = 2,
-    bytes_read: Optional[float] = None,
-    flops: float = 0.0,
-    overhead_cycles: float = 500.0,
-) -> counters_mod.CounterSet:
-    """Run the instrumented kernel and return its counters as a CounterSet.
-
-    The provider hook: ``repro.analysis.providers.InstrumentedKernelProvider``
-    calls this so every counter (``O``, ``N``, active lanes) is read back
-    from the Pallas launch, not synthesized.
-    """
-    _, trace = histogram_instrumented(
-        img, num_bins=num_bins, variant=variant, tile=tile,
-        force_fao=force_fao, weighted=weighted, num_cores=num_cores,
-        waves_per_tile=waves_per_tile, pipeline_depth=pipeline_depth)
-    with telemetry.span("kernel.counters"):
-        return counters_mod.CounterSet.from_trace(
-            trace, label=label, num_cores=num_cores,
-            bytes_read=image_bytes(img) if bytes_read is None else bytes_read,
-            flops=flops, overhead_cycles=overhead_cycles,
-            source="kernel", meta={"op": "histogram", "variant": variant})

@@ -8,7 +8,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import counters as counters_mod
 from repro.core import timing
 from repro.kernels import instrumentation as instr
 from repro.kernels.scatter_add import kernel as sk
@@ -92,41 +91,6 @@ def committed_id_stream_device(ids: jnp.ndarray, num_segments: int, *,
 def default_waves_per_tile(tile: int = sk.DEFAULT_TILE) -> int:
     """The kernel's own tiling: waves issued per grid tile."""
     return tile // instr.LANES
-
-
-def collect_counters(
-    ids,
-    values,
-    num_segments: int,
-    *,
-    label: str = "",
-    tile: int = sk.DEFAULT_TILE,
-    num_cores: int = 8,
-    job_class: int = timing.FAO,
-    waves_per_tile: int | None = None,
-    pipeline_depth: int = 2,
-    bytes_read: float | None = None,
-    flops: float = 0.0,
-    overhead_cycles: float = 500.0,
-) -> counters_mod.CounterSet:
-    """Run the instrumented kernel and return its counters as a CounterSet.
-
-    The provider hook: ``repro.analysis.providers.InstrumentedKernelProvider``
-    calls this so every counter is read back from the Pallas launch, not
-    synthesized.
-    """
-    _, counters = instrumented_scatter_add(
-        ids, values, num_segments, tile=tile, num_cores=num_cores,
-        job_class=job_class, waves_per_tile=waves_per_tile,
-        pipeline_depth=pipeline_depth)
-    if bytes_read is None:
-        bytes_read = float(np.asarray(ids).size * 4)
-    with telemetry.span("kernel.counters"):
-        return counters_mod.CounterSet.from_trace(
-            counters["trace"], label=label, num_cores=num_cores,
-            bytes_read=bytes_read, flops=flops,
-            overhead_cycles=overhead_cycles, source="kernel",
-            meta={"op": "scatter_add"})
 
 
 _scatter_and_degrees = jax.jit(

@@ -186,14 +186,14 @@ def heatmap_from_stream(stream, *, label: str = "",
 def heatmap_for_spec(spec, *, hot_degree: float = DEFAULT_HOT_DEGREE) -> Heatmap:
     """Attribution for a workload spec (kernel or indices source).
 
-    Uses ``TraceProvider.committed_stream`` so the stream, geometry, and
-    counter aggregation match ``Session.profile`` on the same spec bit
-    for bit.  Pre-recorded ``trace``/``run``/``hlo`` sources carry no
-    index stream to attribute and raise ``ValueError``.
+    Uses ``spec.committed_stream()``, the stream the trace provider
+    counts, so the stream, geometry, and counter aggregation match
+    ``Session.profile`` on the same spec bit for bit.  Pre-recorded
+    ``trace``/``run``/``hlo`` sources and ``moe_router`` sources (ids made
+    on the device) carry no host stream to attribute and raise
+    ``ValueError``.
     """
-    from repro.analysis.providers.trace import TraceProvider  # lazy: layering
-    prov = TraceProvider()
-    stream, job_class, wpt = prov.committed_stream(spec)
+    stream, job_class, wpt = spec.committed_stream()
     meta = {}
     if spec.kernel is not None:
         meta = {"op": spec.kernel.op,
@@ -204,4 +204,4 @@ def heatmap_for_spec(spec, *, hot_degree: float = DEFAULT_HOT_DEGREE) -> Heatmap
         pipeline_depth=spec.pipeline_depth or 2,
         bytes_read=spec.bytes_read, flops=spec.flops,
         overhead_cycles=spec.overhead_cycles, hot_degree=hot_degree,
-        source=prov.name, meta=meta)
+        meta=meta)

@@ -26,9 +26,8 @@ Everything here is stdlib-only and imports nothing from the rest of
 other way around.  Importing it never imports jax: the annotation is
 looked up by the first span that runs after another module imported it.
 
-A global enable switch (``set_enabled``) turns every write into a no-op
-so the ``heatmap_overhead`` benchmark can measure the instrumented
-pipeline with telemetry off.
+A global enable switch (``set_enabled``) turns every write into a no-op,
+so the instrumented pipeline can run with telemetry off.
 """
 
 from __future__ import annotations

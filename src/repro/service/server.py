@@ -19,7 +19,7 @@ through deadlines, per-call timeouts, retries, and circuit breakers.
 
 ``serve(config)`` is the blocking CLI entry point (``repro serve``);
 ``ProfilingService`` alone (``start``/``handle``/``stop``) is the
-embeddable form the tests and benchmarks drive in-process.
+embeddable form the tests and ``chip_smoke.py`` drive in-process.
 """
 
 from __future__ import annotations

@@ -67,7 +67,7 @@ def test_heatmap_bit_consistent_with_counterset():
         cset = prov.collect(spec, None)
         assert bitwise_equal(hm.counters, cset)
         # hits sum to the committed stream length (pixels x channels)
-        stream, _, _ = prov.committed_stream(spec)
+        stream, _, _ = spec.committed_stream()
         assert int(hm.hits.sum()) == stream.size
         assert hm.total_hits == stream.size
         # the wave series is the trace's degree array: one entry per

@@ -326,22 +326,6 @@ def test_hlo_provider_requires_compiled_source(sess):
                    hlo_text="HloModule m").resolve_trace()
 
 
-def test_ops_collect_counters_hooks_directly():
-    """The per-family low-level hooks work outside a Session/provider."""
-    from repro.kernels.histogram import ops as hist_ops
-    from repro.kernels.scatter_add import ops as scat_ops
-
-    cset = hist_ops.collect_counters(_image("solid"), label="hook-h",
-                                     force_fao=True)
-    assert cset.source == "kernel" and cset.total_jobs > 0
-    assert cset.bytes_read == 2048 * 4          # image_bytes default
-    ids = _uniform_indices(2)
-    cset2 = scat_ops.collect_counters(
-        ids, np.ones((ids.size, 1), np.float32), 256, label="hook-s")
-    assert cset2.source == "kernel" and cset2.e >= 1.0
-    assert cset2.bytes_read == ids.size * 4
-
-
 def test_scatter_add_providers_agree_bit_for_bit(sess):
     ids = _uniform_indices(num_waves=4, num_bins=128, seed=3)
     vals = np.ones((ids.size, 1), np.float32)
@@ -368,14 +352,25 @@ def test_unweighted_unforced_histogram_maps_to_popc_class(sess):
     assert np.sum(cset.N_p) == cset.total_jobs
 
 
-def test_unknown_kernel_op_raises(sess):
+@pytest.mark.parametrize("entry", [
+    "trace_collect", "trace_collect_batch", "kernel_collect",
+    "resolve_trace", "committed_stream", "heatmap_for_spec"])
+def test_unknown_kernel_op_raises(sess, entry):
+    """Every entry point reaches the spec's one op switch."""
     from repro.analysis import KernelSource
+    from repro.obs import heatmap_for_spec
     spec = WorkloadSpec(label="bad", kernel=KernelSource(op="fft"))
-    for provider in ("trace", "kernel"):
-        with pytest.raises(ValueError, match="unknown kernel op"):
-            sess.collect(spec, provider=provider)
+    call = {
+        "trace_collect": lambda: sess.collect(spec, provider="trace"),
+        "trace_collect_batch": lambda: get_provider("trace").collect_batch(
+            [spec], sess.device),
+        "kernel_collect": lambda: sess.collect(spec, provider="kernel"),
+        "resolve_trace": spec.resolve_trace,
+        "committed_stream": spec.committed_stream,
+        "heatmap_for_spec": lambda: heatmap_for_spec(spec),
+    }[entry]
     with pytest.raises(ValueError, match="unknown kernel op"):
-        spec.resolve_trace()
+        call()
 
 
 def test_spec_rejects_compiled_plus_trace_source():

@@ -100,16 +100,33 @@ def test_routed_tokens_are_counted_per_layer():
 def test_counters_are_those_of_the_routed_ids():
     """The kernel provider's counters equal those of the router's ids,
     read back here and counted by the same kernel from the host."""
-    from repro.kernels.scatter_add import ops as scat_ops
-
     spec, r, x = _spec(50)
-    routed = Session("v5e", provider="kernel").collect(spec)
+    sess = Session("v5e", provider="kernel")
+    routed = sess.collect(spec)
     ids = np.asarray(moe.route({"router": r}, x, CFG)[1])
-    host = scat_ops.collect_counters(ids, np.ones(ids.size, np.float32), 32,
-                                     label="r")
+    host = sess.collect(WorkloadSpec.from_scatter_add(
+        ids, np.ones(ids.size, np.float32), 32, label="r"))
     assert bitwise_equal(routed, host, ignore=("meta",))
     # 256 tokens x top-4 = 1024 ids, padded to one 2048-id tile: 2 waves
     assert routed.num_waves == 2
+
+
+@pytest.mark.parametrize("entry", ["committed_stream", "trace_provider",
+                                   "heatmap_for_spec"])
+def test_routed_ids_have_no_host_stream(entry):
+    """The router's ids are made on the device: no host-stream entry
+    point can synthesize or attribute them."""
+    from repro.obs import heatmap_for_spec
+
+    spec = _spec(70)[0]
+    call = {
+        "committed_stream": spec.committed_stream,
+        "trace_provider": lambda: Session("v5e", provider="trace").collect(
+            spec),
+        "heatmap_for_spec": lambda: heatmap_for_spec(spec),
+    }[entry]
+    with pytest.raises(ValueError, match="no host index stream"):
+        call()
 
 
 def test_a_changed_batch_is_a_memo_miss():
