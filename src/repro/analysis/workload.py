@@ -26,6 +26,8 @@ import hashlib
 import itertools
 import os
 import sys
+import threading
+import weakref
 from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Optional
 
@@ -49,6 +51,12 @@ _FINGERPRINT_BYTES = _telemetry.counter(
     "array bytes hashed by WorkloadSpec.fingerprint, by path "
     "(flat: one sha256 over the buffer; chunked: the sha256 tree)",
     ("path",))
+
+_DEVICE_DIGEST_ARRAYS = _telemetry.counter(
+    "repro_device_digest_arrays_total",
+    "device-resident arrays looked up by WorkloadSpec.fingerprint, by "
+    "result (hit: digest kept from an earlier call; miss: digested on "
+    "the device)", ("result",))
 
 
 _ROUTED_TOKENS = _telemetry.counter(
@@ -101,14 +109,66 @@ def _device_arrays(values) -> list:
     return [v for v in values if isinstance(v, jax.Array)]
 
 
+class _DigestCache:
+    """The device digest of each live ``jax.Array``, kept while it lives.
+
+    A ``jax.Array`` cannot change (once deleted it cannot be read at
+    all), so its digest is a function of the object.  Entries are keyed
+    by ``id`` and hold a weak reference, never the array: the reference's
+    callback retires the entry when the array is collected, and only if
+    the entry is still its own, since ids are reused.  A deleted array is
+    never served.  The lock guards the callback's check-then-delete; it
+    is re-entrant because a collection, and with it a callback, can start
+    on a thread that holds it.
+    """
+
+    def __init__(self) -> None:
+        self._lock = threading.RLock()
+        self._entries: dict = {}     # id -> (weakref, 16-byte digest)
+
+    def get(self, a) -> Optional[bytes]:
+        entry = self._entries.get(id(a))
+        if entry is None or entry[0]() is not a or a.is_deleted():
+            return None
+        return entry[1]
+
+    def put(self, a, digest: bytes) -> None:
+        key = id(a)
+
+        def retire(ref) -> None:
+            with self._lock:
+                if self._entries.get(key, (None,))[0] is ref:
+                    del self._entries[key]
+
+        ref = weakref.ref(a, retire)
+        with self._lock:
+            self._entries[key] = (ref, digest)
+
+
+_DIGESTS = _DigestCache()
+
+
 def _device_digests(arrays: list) -> list:
-    """Digests of device-resident arrays, computed on the device; only
-    the digests come back (``repro.kernels.digest``)."""
+    """Digests of device-resident arrays: kept ones from ``_DIGESTS``,
+    the rest computed on the device, where only the digests come back
+    (``repro.kernels.digest``), and kept."""
+    out = [_DIGESTS.get(a) for a in arrays]
+    misses = [a for a, d in zip(arrays, out) if d is None]
+    _DEVICE_DIGEST_ARRAYS.inc(len(arrays) - len(misses), result="hit")
+    _DEVICE_DIGEST_ARRAYS.inc(len(misses), result="miss")
+    if not misses:
+        return out
     from repro.kernels import digest  # lazy: jax
 
-    with _telemetry.span("kernel.digest", arrays=len(arrays),
-                         bytes=sum(a.nbytes for a in arrays)):
-        return digest.digests(arrays)
+    with _telemetry.span("kernel.digest", arrays=len(misses),
+                         bytes=sum(a.nbytes for a in misses),
+                         cached=len(arrays) - len(misses)):
+        fresh = iter(digest.digests(misses))
+    for i, d in enumerate(out):
+        if d is None:
+            out[i] = next(fresh)
+            _DIGESTS.put(arrays[i], out[i])
+    return out
 
 
 @dataclasses.dataclass(frozen=True)
@@ -220,7 +280,10 @@ class WorkloadSpec:
         the device and never copied to the host (``kernel.digest``): the
         fingerprint then holds their 128-bit device digests
         (``repro.kernels.digest``, not cryptographic), with dtype and
-        shape, under the sha256 of the rest.
+        shape, under the sha256 of the rest.  A ``jax.Array`` is
+        immutable, so its digest is kept while the array lives and a
+        later spec that holds the same array does not digest it again;
+        a host array may change, so it is hashed on every call.
         """
         if self.run is not None or self.compiled is not None:
             return None

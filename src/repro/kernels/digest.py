@@ -3,8 +3,8 @@
 ``WorkloadSpec.fingerprint`` keys the session memo and the persistent
 counter cache by content.  For an array already on the device, hashing
 it on the host would copy it back first (235 MB for one batch of
-DeepSeek-V3 hidden states).  Here one jitted program reads the arrays
-where they are and only a few words per array come back.
+DeepSeek-V3 hidden states).  Here a jitted program reads each array
+where it is and only a few words per array come back.
 
 The digest of an array is four 32-bit lanes.  Each element's bits,
 widened to a 32-bit word ``w_i`` (an 8-byte element gives two), are
@@ -74,13 +74,16 @@ def _digest(a: jnp.ndarray) -> jnp.ndarray:
                       for k in _LANE_KEYS])
 
 
-@jax.jit
-def _digest_all(arrays: tuple) -> jnp.ndarray:
-    return jnp.stack([_digest(a) for a in arrays])
+#: one program per (shape, dtype), whichever other arrays are digested
+#: beside it: a spec's cached arrays drop out of the call (see
+#: ``WorkloadSpec.fingerprint``), so a program over the tuple of the rest
+#: would compile anew for each new mix of hits and misses.
+_digest_one = jax.jit(_digest)
 
 
 def digests(arrays) -> list:
-    """One 16-byte digest per device-resident array, in order: one
-    program for all of them and one explicit copy of their digests."""
-    out = jax.device_get(_digest_all(tuple(arrays)))
+    """One 16-byte digest per device-resident array, in order: each
+    array's program dispatched in turn, then one explicit copy of all
+    their digests."""
+    out = jax.device_get([_digest_one(a) for a in arrays])
     return [np.asarray(row, "<u4").tobytes() for row in out]

@@ -84,6 +84,27 @@ def test_router_steps_nest_under_the_collection():
         loads.max() / ids.size, rel=1e-6)
 
 
+def test_a_batch_shared_by_two_layers_is_digested_by_the_first():
+    sess = Session("v5e", provider="kernel")
+    sess.profile(_spec(0)[0])                   # compile outside the scope
+    first, _, x = _spec(20, layer=3)
+    r4 = moe.init_router(jax.random.key(30), CFG)
+    r4["bias"] = 0.02 * jax.random.normal(jax.random.key(31), (32,))
+    second = WorkloadSpec.from_moe_router({"router": r4}, x, CFG,
+                                          label="r4", layer=4)
+    digests = []
+    for spec in (first, second):
+        with trace_scope() as rec:
+            sess.profile(spec)
+        (d,) = _by_name(rec["spans"], "kernel.digest")
+        digests.append(d["attrs"])
+    assert digests[0]["bytes"] >= x.nbytes
+    assert digests[0]["cached"] == 0
+    assert digests[1]["bytes"] == r4["w"].nbytes + r4["bias"].nbytes
+    assert digests[1]["bytes"] < x.nbytes
+    assert digests[1]["arrays"] == 2 and digests[1]["cached"] >= 1
+
+
 def test_routed_tokens_are_counted_per_layer():
     routed = telemetry.counter("repro_moe_routed_tokens_total", "",
                                ("layer",))

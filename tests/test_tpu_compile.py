@@ -13,7 +13,7 @@ The sizes are the chip smoke test's: the paper's largest histogram image
 tokens (65,536 ids), a segment sum over 16,384 segments (four segment
 blocks) at D=128, and flash attention at (8, 2048, 128) bf16; and the
 dsv3-routed cell's two programs, DeepSeek-V3's router with its count and
-the device digest of its 16,384-token batch.
+the device digest of its batch, gate and bias, one program per array.
 """
 
 import os
@@ -132,10 +132,11 @@ def test_routed_count_program_compiles(compile_for_chip):
 
 
 def test_device_digest_compiles(compile_for_chip):
+    """The digest's program for each array of a dsv3-routed spec: the
+    batch, the gate and the bias, one program per (shape, dtype)."""
     from repro.kernels import digest
 
-    compiled = compile_for_chip(
-        lambda x, w, b: digest._digest_all((x, w, b)),
-        ((16384, 7168), jnp.bfloat16), ((7168, 256), jnp.float32),
-        ((256,), jnp.float32))
-    assert compiled.memory_analysis().temp_size_in_bytes < HBM_BYTES
+    for shape in (((16384, 7168), jnp.bfloat16), ((7168, 256), jnp.float32),
+                  ((256,), jnp.float32)):
+        compiled = compile_for_chip(digest._digest_one, shape)
+        assert compiled.memory_analysis().temp_size_in_bytes < HBM_BYTES
